@@ -275,6 +275,8 @@ def main() -> None:
                     help="run only the write-path section (CI smoke): "
                          "writes {'ingest': ...} to --out and exits")
     args = ap.parse_args()
+    from repro.jax_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks.common import corpus
     from repro.core.collate import collation_stats, collate

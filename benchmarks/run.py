@@ -2,7 +2,9 @@
 
 ``PYTHONPATH=src python -m benchmarks.run [--only table14] [--skip-roofline]``
 Prints ``name,us_per_call,derived`` CSV rows (paper-table quantities in the
-derived column), then the §Roofline report from results/dryrun.
+derived column), then the §Roofline report from results/dryrun.  A bench
+that raises prints an ERROR row, the others still run, and the exit code
+is then 1.
 """
 
 from __future__ import annotations
@@ -12,7 +14,7 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--skip-roofline", action="store_true")
@@ -36,6 +38,7 @@ def main() -> None:
         tables.device_query_bench,
     ]
     emit = Emitter()
+    failed = []
     print(f"# benchmarks over synthetic WSJ1-like corpus "
           f"(BENCH_SCALE={BENCH_DOCS} docs)")
     print("name,us_per_call,derived")
@@ -46,6 +49,7 @@ def main() -> None:
         try:
             bench(emit)
         except Exception as e:  # noqa: BLE001
+            failed.append(bench.__name__)
             print(f"{bench.__name__},nan,ERROR {type(e).__name__}: {e}",
                   flush=True)
         print(f"# {bench.__name__} done in {time.time()-t0:.1f}s",
@@ -58,7 +62,12 @@ def main() -> None:
             report()
         except Exception as e:  # noqa: BLE001
             print(f"# roofline report unavailable: {e}")
+    if failed:
+        print(f"# benches that errored: {', '.join(failed)}",
+              file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

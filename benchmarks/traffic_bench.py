@@ -45,6 +45,7 @@ from repro.core.lifecycle import FreezePolicy  # noqa: E402
 from repro.core.sharded_index import ShardedEngine  # noqa: E402
 from repro.engine import Engine  # noqa: E402
 from repro.engine.types import Query  # noqa: E402
+from repro.jax_cache import enable_compile_cache  # noqa: E402
 from repro.serve import (  # noqa: E402
     SLOSpec,
     WorkloadSpec,
@@ -174,6 +175,7 @@ def main() -> int:
                          "measured-crossover planner route (slow without a "
                          "real accelerator: interpret-mode compile cost)")
     args = ap.parse_args()
+    enable_compile_cache()
     backend = None if args.backend == "default" else args.backend
 
     events = 400 if args.smoke else args.events

@@ -5,8 +5,9 @@
 Demonstrates the engine lifecycle end to end:
 
   1. ingest through the Engine (doclens/vocab/f_t tracked for you);
-  2. query mid-stream on every backend — host cursors, the device oracle,
-     and the Pallas kernels — and watch the planner route;
+  2. query mid-stream on the host cursors and the device path (the XLA
+     flavour of the fused kernel; the Pallas flavour does not compile for
+     a TPU, so it is left out) and watch the planner route;
   3. collate once (the freeze), keep ingesting, and query the device
      backend again: the frozen image plus the incremental DeltaImage answer
      for documents the device has never been collated over;
@@ -33,9 +34,9 @@ for d in docs[:700]:
 sample = [t for t in docs[0][:4]]
 print(f"ingested {eng.index.num_docs} docs; probe terms: {sample[:2]}")
 
-# -- 2: same query, every backend -----------------------------------------
+# -- 2: same query, host and device ----------------------------------------
 q = Query(terms=tuple(sample[:2]), mode="ranked_tfidf", k=5)
-for backend in ("host", "device", "pallas"):
+for backend in ("host", "device"):
     r = eng.execute(Query(terms=q.terms, mode=q.mode, k=q.k,
                           backend=backend))
     print(f"  {backend:7s} top-5 docs {r.docids.tolist()} "

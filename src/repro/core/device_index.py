@@ -404,6 +404,50 @@ def decode_blocks(blocks: jnp.ndarray, start: jnp.ndarray, end: jnp.ndarray,
 
 
 # --------------------------------------------------------------------------
+# step 4 helper: a log1p the device computes to f32 precision
+# --------------------------------------------------------------------------
+
+
+_LN2_HI = np.float32(6.9313812256e-01)   # 17 significant bits: e*hi is exact
+_LN2_LO = np.float32(9.0580006145e-06)
+_SQRT2 = np.float32(1.4142135)
+
+
+def precise_log1p(x: jnp.ndarray) -> jnp.ndarray:
+    """``log1p(x)`` for ``x >= 0`` in f32, to a few ulps on every backend.
+
+    XLA's f32 ``log``/``log1p`` on a TPU v5e are off by up to 3.7e-4
+    relative (measured against f64 over integers, ratios N/f_t and values
+    in (0, 2)), which moves ranked scores past the 1e-5 the device answers
+    are held to against the host's f64 scoring.  This uses only multiply,
+    add, divide and bit operations, which the TPU rounds like the CPU.
+    Both branches evaluate log(m) = 2 atanh(s), s = (m - 1)/(m + 1), whose
+    series to s^9 is exact to f32 for |s| < 0.172:
+      * x < sqrt(2) - 1: m = 1 + x taken as s = x / (2 + x), so no
+        cancellation (XLA folds ``(1 + x) - 1`` to ``x``, so a rounding
+        correction built from it would vanish);
+      * otherwise u = 1 + x is split as 2^e * m with m in [sqrt(1/2),
+        sqrt(2)); log(u) >= 0.34 there, so rounding 1 + x costs < 2 ulps.
+    """
+    x = x.astype(jnp.float32)
+    u = 1.0 + x
+    bits = jax.lax.bitcast_convert_type(u, jnp.int32)
+    e = (bits >> 23) - 127
+    m = jax.lax.bitcast_convert_type((bits & 0x007FFFFF) | 0x3F800000,
+                                     jnp.float32)            # [1, 2)
+    big = m > _SQRT2
+    m = jnp.where(big, m * 0.5, m)
+    fm = m - 1.0                                            # exact
+    small = x < _SQRT2 - 1.0
+    s = jnp.where(small, x / (2.0 + x), fm / (2.0 + fm))
+    ef = jnp.where(small, 0, e + big.astype(jnp.int32)).astype(jnp.float32)
+    z = s * s
+    poly = z * (2.0 / 3 + z * (2.0 / 5 + z * (2.0 / 7 + z * (2.0 / 9))))
+    log_m = 2.0 * s + s * poly
+    return ef * _LN2_HI + (log_m + ef * _LN2_LO)
+
+
+# --------------------------------------------------------------------------
 # steps 1+3+4: full batched query
 # --------------------------------------------------------------------------
 
@@ -507,7 +551,7 @@ def query_step(image: DeviceIndex, qterms: jnp.ndarray, qmask: jnp.ndarray,
     if mode == "bm25":
         # Okapi BM25 (k1=0.9, b=0.4): saturated tf with length normalization
         k1, b = 0.9, 0.4
-        idf = jnp.log1p((Ns - ft + 0.5) / (ft + 0.5))
+        idf = precise_log1p((Ns - ft + 0.5) / (ft + 0.5))
         idf = (idf * qmask.reshape(-1)).reshape(Q, T)
         dl = doclens[docid.reshape(Q, -1)]                  # (Q, P)
         avgdl = (jnp.maximum(doclens[1:].sum() / Ns, 1e-9)
@@ -518,9 +562,13 @@ def query_step(image: DeviceIndex, qterms: jnp.ndarray, qmask: jnp.ndarray,
         w = (tf.reshape(Q, T, max_blocks, B)
              * idf[:, :, None, None]).reshape(Q, -1)
     else:
-        idf = jnp.log1p(Ns / ft)
+        idf = precise_log1p(Ns / ft)
         idf = (idf * qmask.reshape(-1)).reshape(Q, T)
-        w = jnp.log1p(jnp.where(valid, f, 0).astype(jnp.float32))
+        # the barrier keeps XLA from re-deriving the log inside every
+        # consumer of w (the sort gather below): without it the four-chip
+        # mesh step took over 6 minutes to compile for a v5e, 21 s with it
+        w = jax.lax.optimization_barrier(
+            precise_log1p(jnp.where(valid, f, 0)))
         w = w.reshape(Q, T, max_blocks, B) * idf[:, :, None, None]
         w = w.reshape(Q, -1)
     if mode in ("ranked_sparse", "bm25"):
@@ -530,21 +578,22 @@ def query_step(image: DeviceIndex, qterms: jnp.ndarray, qmask: jnp.ndarray,
         order = jnp.argsort(flat_docs, axis=1)
         d_s = jnp.take_along_axis(flat_docs, order, axis=1)   # (Q, P)
         w_s = jnp.take_along_axis(w, order, axis=1)
-        csum = jnp.cumsum(w_s, axis=1)
         P = d_s.shape[1]
         nxt = jnp.concatenate(
             [d_s[:, 1:], jnp.full((Q, 1), -1, d_s.dtype)], axis=1)
         is_end = d_s != nxt                                   # run ends
-        # csum at the previous run end, gather-free (same trick as decode)
-        pos = jnp.arange(P)[None, :]
-        prev_end = jax.lax.associative_scan(
-            jnp.maximum, jnp.where(is_end, pos, -1), axis=1)
-        prev_end = jnp.concatenate(
-            [jnp.full((Q, 1), -1), prev_end[:, :-1]], axis=1)
-        prev_csum = jnp.where(
-            prev_end >= 0,
-            jnp.take_along_axis(csum, jnp.maximum(prev_end, 0), axis=1), 0.0)
-        run_score = jnp.where(is_end & (d_s > 0), csum - prev_csum, -jnp.inf)
+        # each term holds a docid at most once, so a docid's run is at most
+        # T long: sum it from the T - 1 slots before its end.  (A difference
+        # of prefix sums over the whole row loses 1e-5 of a score to f32
+        # cancellation once a query touches ~10^4 postings.)
+        run = w_s
+        for j in range(1, min(T, P)):
+            same = jnp.concatenate(
+                [jnp.zeros((Q, j), bool), d_s[:, j:] == d_s[:, :-j]], axis=1)
+            prev = jnp.concatenate(
+                [jnp.zeros((Q, j), w_s.dtype), w_s[:, :-j]], axis=1)
+            run = run + jnp.where(same, prev, 0.0)
+        run_score = jnp.where(is_end & (d_s > 0), run, -jnp.inf)
         # k may exceed the posting-slot count (top_k requires k <= minor
         # dim); clamping is exact — distinct scored docids never exceed P
         top_s, pos_k = jax.lax.top_k(run_score, min(k, P))

@@ -45,15 +45,6 @@ from jax.sharding import PartitionSpec as P
 
 from .device_index import DeviceIndex, decode_blocks, query_step
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax < 0.5: experimental home, check_vma spelled check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_compat(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-
 
 def stack_images(images: list[DeviceIndex]) -> DeviceIndex:
     """Concatenate per-shard images along a leading shard axis.
@@ -148,8 +139,8 @@ def make_sharded_query_step(mesh, *, k: int = 10, max_blocks: int = 64,
 
         in_specs = img_specs + (off_spec, q_spec, q_spec)
         out_specs = (P("model", doc_axes), P("model"))
-        mapped = shard_map(fn_conj, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
+        mapped = jax.shard_map(fn_conj, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
         in_sharding = tuple(jax.NamedSharding(mesh, s) for s in in_specs)
         out_sharding = tuple(jax.NamedSharding(mesh, s) for s in out_specs)
         return mapped, in_sharding, out_sharding
@@ -181,8 +172,8 @@ def make_sharded_query_step(mesh, *, k: int = 10, max_blocks: int = 64,
     # NB: shard_map requires explicit specs for every input leaf
     in_specs = img_specs + (off_spec, q_spec, q_spec)
     out_specs = (P("model", None), P("model", None))
-    mapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     in_sharding = tuple(jax.NamedSharding(mesh, s) for s in in_specs)
     out_sharding = tuple(jax.NamedSharding(mesh, s) for s in out_specs)
     return mapped, in_sharding, out_sharding

@@ -63,8 +63,10 @@ class SyntheticCorpus:
 
     def doc_terms(self) -> Iterator[list[str]]:
         """Yield documents as term lists (term ids rendered to strings)."""
+        # render the universe once; per-document rendering is a gather
+        names = np.array([_term_name(i) for i in range(self.spec.universe)])
         for ids in self.doc_term_ids():
-            yield [_term_name(int(i)) for i in ids]
+            yield names[ids].tolist()
 
     def doc_term_ids(self) -> Iterator[np.ndarray]:
         spec = self.spec

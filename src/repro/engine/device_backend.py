@@ -42,6 +42,8 @@ grows.
 
 from __future__ import annotations
 
+from collections import Counter
+
 import numpy as np
 
 from ..core.device_index import (
@@ -68,7 +70,10 @@ class ResidentImageManager:
     Lifecycle counters double as the amortization evidence the benchmarks
     record: ``frozen_uploads`` bumps only at freeze (collation) time while
     ``batches_served`` bumps per fused launch — steady-state serving shows
-    many batches per upload.
+    many batches per upload.  ``launch_buckets`` counts launches per
+    compiled-program key (mode, k, Qn, T, per-image packed caps, frozen and
+    delta block counts, vocab and doc capacity, liveness mask or not): the
+    number of distinct keys is the number of programs serving compiled.
     """
 
     def __init__(self, engine, decode_fn=None):
@@ -92,6 +97,7 @@ class ResidentImageManager:
         self.epoch = 0                                 # freeze epochs seen
         self.frozen_uploads = 0                        # resident-image uploads
         self.batches_served = 0                        # fused launches
+        self.launch_buckets: Counter = Counter()
 
     # ------------------------------------------------------------------
     # image lifecycle
@@ -260,10 +266,12 @@ class ResidentImageManager:
 
 def fused_execute(engine, resident: ResidentImageManager,
                   batch: list[Query], mode: str, k: int, *, flavor: str,
-                  interpret: bool, name: str) -> list[QueryResult]:
+                  name: str, interpret: bool | None = None
+                  ) -> list[QueryResult]:
     """Answer one (mode, k) query group with a single fused launch over the
     resident images.  Shared by the device (flavor="ref") and pallas
-    (flavor="pallas") backends — identical math, one resident state."""
+    (flavor="pallas") backends — identical math, one resident state.
+    ``interpret`` applies to the pallas flavour only."""
     import jax.numpy as jnp
     eng = engine
     N = eng.index.num_docs
@@ -309,6 +317,11 @@ def fused_execute(engine, resident: ResidentImageManager,
                   n_stat=resident._n_stat, avg_stat=resident._avg_stat,
                   alive=resident._alive, flavor=flavor, interpret=interpret)
     resident.batches_served += 1
+    frozen, delta = resident.images
+    resident.launch_buckets[(
+        mode, k, Qn, T, tuple(caps), int(frozen.blocks.shape[0]),
+        int(delta.blocks.shape[0]), int(frozen.term_slot.shape[0]),
+        int(frozen.num_docs), resident._alive is not None)] += 1
     if mode == "conjunctive":
         matches = np.asarray(out)
         for row, i in enumerate(live):
@@ -371,8 +384,7 @@ class DeviceBackend(Backend):
             batch = [queries[i] for i in idxs]
             if self.use_fused:
                 res = fused_execute(self.engine, self.resident, batch, mode,
-                                    k, flavor="ref", interpret=True,
-                                    name=self.name)
+                                    k, flavor="ref", name=self.name)
             else:
                 res = self._run_group_split(batch, mode, k)
             for i, r in zip(idxs, res):
@@ -391,7 +403,7 @@ class DeviceBackend(Backend):
             # image's k; the fused path masks inside the accumulator —
             # delegate to it whenever deletes are outstanding
             return fused_execute(eng, mgr, batch, mode, k, flavor="ref",
-                                 interpret=True, name=self.name)
+                                 name=self.name)
         N = eng.index.num_docs
         tids: list[list[int] | None] = []
         for q in batch:

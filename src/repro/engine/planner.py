@@ -36,6 +36,13 @@ rivals execution cost.  Routing rules, in priority order:
    it is microseconds;
 6. everything else stays on the host, whose seek_GEQ skipping beats a
    device round-trip on short chains.
+
+The Pallas rules (the crossover's pallas column and rule 4) apply only
+when the config opts in with ``allow_pallas=True``: the Pallas flavour of
+the fused kernel does not compile for a TPU (v5e refuses its rank-1 block
+specs, ``jnp.flip``, scatter-add and ``lax.top_k``), so by default nothing
+is routed there on any platform, and tests and chips take the same routes.
+A query or engine that forces ``backend="pallas"`` still gets it.
 """
 
 from __future__ import annotations
@@ -127,7 +134,7 @@ class PlannerConfig:
     pallas_min_postings: int = 2048  # candidate volume at which kernels win
     tiered_max_volume: int = 2048   # volume ceiling for tiered routing
     allow_device: bool = True
-    allow_pallas: bool = True
+    allow_pallas: bool = False      # opt-in: no TPU build (module doc)
     allow_tiered: bool = True
     crossover: CrossoverTable | None = None  # measured thresholds (bench)
 

@@ -143,8 +143,7 @@ def _decode_tile(b_ref, start_ref, end_ref, g_ref, f_ref, v_ref, *, F: int):
 
 def dvbyte_decode_kernel(blocks: jnp.ndarray, start: jnp.ndarray,
                          end: jnp.ndarray, F: int,
-                         tile: int = DEFAULT_TILE,
-                         interpret: bool = True):
+                         tile: int = DEFAULT_TILE, *, interpret: bool):
     """pallas_call wrapper: decode (NB, B) blocks, tiled TB rows at a time."""
     NB, B = blocks.shape
     if NB % tile != 0:

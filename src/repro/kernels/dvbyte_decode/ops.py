@@ -6,25 +6,31 @@ from functools import partial
 
 import jax
 
+from .. import registry
 from .kernel import DEFAULT_TILE, dvbyte_decode_kernel
 
 
 @partial(jax.jit, static_argnames=("F", "tile", "interpret"))
 def dvbyte_decode_blocks(blocks, start, end, F: int = 4,
-                         tile: int = DEFAULT_TILE, interpret: bool = True):
+                         tile: int = DEFAULT_TILE,
+                         interpret: bool | None = None):
     """Decode a batch of B-byte Double-VByte blocks on TPU.
 
     Drop-in replacement for ``repro.core.device_index.decode_blocks`` (pass
-    it as ``decode_fn`` to ``query_step``).  ``interpret=True`` executes the
-    kernel body in Python on CPU; on a real TPU pass ``interpret=False``.
+    it as ``decode_fn`` to ``query_step``).  ``interpret=None`` runs the
+    kernel body in the Pallas interpreter everywhere but on a TPU.
     """
+    if interpret is None:
+        interpret = registry.default_interpret()
     return dvbyte_decode_kernel(blocks, start, end, F, tile=tile,
                                 interpret=interpret)
 
 
 def as_decode_fn(F: int = 4, tile: int = DEFAULT_TILE,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """Adapter matching the ``decode_fn(blocks, start, end, F)`` signature."""
+    if interpret is None:
+        interpret = registry.default_interpret()
 
     def fn(blocks, start, end, F_):
         return dvbyte_decode_kernel(blocks, start, end, F_, tile=tile,
@@ -32,8 +38,6 @@ def as_decode_fn(F: int = 4, tile: int = DEFAULT_TILE,
 
     return fn
 
-
-from .. import registry  # noqa: E402
 
 registry.register(registry.KernelSpec(
     name="dvbyte_decode", fn=dvbyte_decode_blocks,

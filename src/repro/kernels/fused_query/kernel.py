@@ -59,7 +59,7 @@ def _pad_q(a: jnp.ndarray, pad: int) -> jnp.ndarray:
 
 def fused_query_kernel(parts, nterms, doclens, bm25_norm, *, mode: str,
                        k: int, F: int, cap: int, tq: int = DEFAULT_TQ,
-                       interpret: bool = True, alive=None):
+                       interpret: bool, alive=None):
     """Launch the fused kernel over per-image packed part tuples.
 
     ``parts`` is a tuple of (gat, start, end, seg, lastd0, dnum0, widf)

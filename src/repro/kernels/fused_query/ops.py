@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from ...core.blockstore import H
-from ...core.device_index import DeltaIndex
+from ...core.device_index import DeltaIndex, precise_log1p
+from .. import registry
 from .kernel import DEFAULT_TQ, fused_query_kernel
 from .ref import BM25_B, BM25_K1, fused_tile
 
@@ -94,9 +95,9 @@ def _prep_image(image, qterms, qmask, Ns, max_blocks: int, mode: str):
     else:
         ft = jnp.maximum(image.term_ft[flat], 1).astype(jnp.float32)
         if mode == "bm25":
-            widf = jnp.log1p((Ns - ft + 0.5) / (ft + 0.5))
+            widf = precise_log1p((Ns - ft + 0.5) / (ft + 0.5))
         else:
-            widf = jnp.log1p(Ns / ft)
+            widf = precise_log1p(Ns / ft)
         widf = (widf * qmask.reshape(-1)).reshape(Q, T)
         widf_s = jnp.where(valid, jnp.take_along_axis(widf, t_of, axis=1),
                            0.0)
@@ -111,7 +112,7 @@ def fused_query(images, qterms, qmask, *, mode: str = "ranked_tfidf",
                 n_stat: jnp.ndarray | None = None,
                 avg_stat: jnp.ndarray | None = None,
                 alive: jnp.ndarray | None = None,
-                flavor: str = "ref", interpret: bool = True,
+                flavor: str = "ref", interpret: bool | None = None,
                 tq: int = DEFAULT_TQ):
     """One fused launch answering ``qterms``/``qmask`` against ``images``.
 
@@ -133,6 +134,8 @@ def fused_query(images, qterms, qmask, *, mode: str = "ranked_tfidf",
         0) — None skips masking entirely, keeping the no-delete path
         byte-identical to its pre-deletion compilation.
       flavor: "pallas" (the kernel) or "ref" (same math inline).
+      interpret: Pallas interpret mode for ``flavor="pallas"``; None
+        interprets everywhere but on a TPU.  The "ref" flavour ignores it.
 
     Returns ``matches (Q, cap+1) bool`` for conjunctive, else
     ``(top_d (Q, kk) i32, top_s (Q, kk) f32)`` in canonical order
@@ -162,14 +165,14 @@ def fused_query(images, qterms, qmask, *, mode: str = "ranked_tfidf",
         dl = jnp.zeros(1, jnp.float32)
     alive_f = None if alive is None else alive.astype(jnp.uint32)
     if flavor == "pallas":
+        if interpret is None:
+            interpret = registry.default_interpret()
         return fused_query_kernel(parts, nterms, dl, norm, mode=mode, k=k,
                                   F=F, cap=cap, tq=tq, interpret=interpret,
                                   alive=alive_f)
     return fused_tile(parts, nterms, dl, norm, mode=mode, k=k, F=F, cap=cap,
                       alive=alive_f)
 
-
-from .. import registry  # noqa: E402
 
 registry.register(registry.KernelSpec(
     name="fused_query", fn=fused_query, modes=FUSED_MODES,

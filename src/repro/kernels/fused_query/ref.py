@@ -60,6 +60,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...core.device_index import precise_log1p
+
 BM25_K1 = 0.9
 BM25_B = 0.4
 
@@ -285,7 +287,7 @@ def fused_tile(parts, nterms, doclens, bm25_norm, *, mode: str, k: int,
                 fv + bm25_norm[0] + bm25_norm[1] * dl)
             w = tf * widf[:, :, None]
         else:
-            w = jnp.log1p(fv) * widf[:, :, None]
+            w = precise_log1p(fv) * widf[:, :, None]
         w = jnp.where(valid, w, 0.0)
         score = _scatter_add(score, docid.reshape(TQ, -1),
                              w.reshape(TQ, -1))

@@ -44,7 +44,7 @@ def _intersect_tile(a_ref, b_ref, o_ref):
 
 def intersect_kernel(a: jnp.ndarray, b: jnp.ndarray,
                      tile_a: int = DEFAULT_TILE, tile_b: int = DEFAULT_TILE,
-                     interpret: bool = True) -> jnp.ndarray:
+                     *, interpret: bool) -> jnp.ndarray:
     """flags[i] = a[i] ∈ b, for sorted, PAD-padded int32 vectors."""
     na, nb = a.shape[0], b.shape[0]
     pa = (-na) % tile_a

@@ -12,8 +12,12 @@ from .kernel import DEFAULT_TILE, intersect_kernel
 
 @partial(jax.jit, static_argnames=("tile_a", "tile_b", "interpret"))
 def intersect_sorted(a, b, tile_a: int = DEFAULT_TILE,
-                     tile_b: int = DEFAULT_TILE, interpret: bool = True):
-    """Membership flags of sorted int32 list ``a`` in sorted list ``b``."""
+                     tile_b: int = DEFAULT_TILE,
+                     interpret: bool | None = None):
+    """Membership flags of sorted int32 list ``a`` in sorted list ``b``.
+    ``interpret=None`` interprets the kernel everywhere but on a TPU."""
+    if interpret is None:
+        interpret = registry.default_interpret()
     return intersect_kernel(a, b, tile_a=tile_a, tile_b=tile_b,
                             interpret=interpret)
 

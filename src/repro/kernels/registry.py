@@ -35,9 +35,9 @@ class KernelSpec:
     """One registered kernel entry point.
 
     ``modes`` names the engine query modes the op accelerates (empty for ops
-    outside the term-query path, e.g. dense two-tower scoring); ``interpret``
-    notes whether the default entry point runs the Pallas body in interpret
-    mode (CPU-safe) unless overridden.
+    outside the term-query path, e.g. dense two-tower scoring).  Every entry
+    point takes ``interpret=None``, which resolves through
+    :func:`default_interpret`.
     """
 
     name: str

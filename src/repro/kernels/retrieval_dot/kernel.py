@@ -38,7 +38,7 @@ def _dot_tile(q_ref, c_ref, o_ref):
 def retrieval_dot_kernel(q: jnp.ndarray, cand: jnp.ndarray,
                          tile_q: int = TILE_Q, tile_n: int = TILE_N,
                          tile_d: int = TILE_D,
-                         interpret: bool = True) -> jnp.ndarray:
+                         *, interpret: bool) -> jnp.ndarray:
     """scores (q, n) = q @ cand^T, tiled for VMEM/MXU."""
     Q, D = q.shape
     N, D2 = cand.shape

@@ -47,7 +47,7 @@ def _score_tile(d_ref, w_ref, o_ref):
 
 def score_kernel(docids: jnp.ndarray, weights: jnp.ndarray, n_docs: int,
                  tile_m: int = DEFAULT_TILE_M, tile_n: int = DEFAULT_TILE_N,
-                 interpret: bool = True) -> jnp.ndarray:
+                 *, interpret: bool) -> jnp.ndarray:
     """Dense scores over docid space [0, n_docs): scatter-add of weights."""
     M = docids.shape[0]
     pm = (-M) % tile_m

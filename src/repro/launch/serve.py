@@ -98,6 +98,8 @@ def main():
     ap.add_argument("--queries", type=int, default=100)
     ap.add_argument("--steps", type=int, default=32)
     args = ap.parse_args()
+    from repro.jax_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "index":
         serve_index(args.docs, args.queries)
     else:

@@ -74,7 +74,9 @@ def zipf_docs():
 @pytest.fixture(scope="session")
 def host_mesh():
     import jax
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def naive_phrase(docs, terms):

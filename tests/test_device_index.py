@@ -1,12 +1,14 @@
 """Device (JAX) query engine vs host engines; kernel-backed decode path."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import query as Q
 from repro.core.collate import collate
-from repro.core.device_index import build_device_image, query_step
+from repro.core.device_index import (build_device_image, precise_log1p,
+                                      query_step)
 from repro.core.index import DynamicIndex
 from repro.kernels.dvbyte_decode.ops import as_decode_fn
 
@@ -20,6 +22,25 @@ def image(zipf_docs):
     col = collate(idx)
     img = build_device_image(col, [t.encode() for t in vocab])
     return vocab, col, img
+
+
+@pytest.mark.parametrize("kind", ["tf", "idf", "bm25_idf", "wide"])
+def test_precise_log1p_matches_f64(kind):
+    """The device scoring log1p stays within a few f32 ulps of f64 on the
+    arguments scoring feeds it, jitted (XLA folds naive rounding fixes)."""
+    rng = np.random.default_rng(0)
+    ft = np.arange(1, 100_001, dtype=np.float64)
+    x = {"tf": np.arange(0, 100_001, dtype=np.float64),
+         "idf": 98_732.0 / ft,
+         "bm25_idf": np.maximum(98_732.0 - ft + 0.5, 0.5) / (ft + 0.5),
+         "wide": np.exp(rng.uniform(-30.0, 16.0, 100_000))}[kind]
+    x32 = x.astype(np.float32)
+    got = np.asarray(jax.jit(precise_log1p)(jnp.asarray(x32)), np.float64)
+    want = np.log1p(x32.astype(np.float64))
+    assert np.all(got[want == 0] == 0)
+    nz = want > 0
+    rel = np.abs(got[nz] - want[nz]) / want[nz]
+    assert rel.max() < 4e-7, (rel.max(), x32[nz][rel.argmax()])
 
 
 def test_requires_collated(zipf_docs):
@@ -44,6 +65,26 @@ def test_ranked_matches_host(image):
             col, [vocab[i] for i in terms], k=10)
         got = np.sort(np.asarray(s_dev[0]))[::-1][: len(s_host)]
         assert np.allclose(got, s_host, rtol=1e-5)
+
+
+def test_ranked_sparse_exact_over_many_postings():
+    """Sort-based aggregation keeps f32 precision when a query touches
+    ~25,000 postings (the four-chip phase's scale): summing runs as
+    differences of whole-row prefix sums was off by ~1e-3 here."""
+    docs = [["a"] * (1 + d % 3) + ["b"] * (d % 2) + ["c"] * (d % 5 > 0)
+            for d in range(10_000)]
+    idx = DynamicIndex(B=64, growth="const")
+    for doc in docs:
+        idx.add_document(doc)
+    col = collate(idx)
+    vocab = ["a", "b", "c"]
+    img = build_device_image(col, [t.encode() for t in vocab])
+    qt = jnp.asarray([[0, 1, 2]], jnp.int32)
+    qm = jnp.ones((1, 3), bool)
+    _, s_dev = query_step(img, qt, qm, k=10, mode="ranked_sparse",
+                          max_blocks=int(img.term_nblk.max()))
+    _, s_host = Q.ranked_disjunctive_taat(col, vocab, k=10)
+    assert np.allclose(np.asarray(s_dev[0]), s_host, rtol=1e-6, atol=0)
 
 
 def test_conjunctive_matches_host(image):

@@ -8,6 +8,7 @@ import pytest
 from repro.core import query as Q
 from repro.core.sharded_index import ShardedEngine
 from repro.engine import Engine, PlannerConfig, Query, UnsupportedQueryError
+from repro.engine.types import TermStats
 from repro.serve import QueryService
 
 
@@ -203,18 +204,62 @@ def test_planner_batches_route_to_device(engine_const):
     res = eng.execute_many(batch)
     assert all(r.backend == "device" for r in res)
     single = eng.execute(Query(terms=(vocab[40],), mode="ranked_tfidf"))
-    assert single.backend in ("host", "pallas")  # small batch never device
+    assert single.backend == "host"  # small batch never device
 
 
 def test_planner_volume_threshold(engine_const):
     vocab, eng = engine_const
-    cfg = PlannerConfig(pallas_min_postings=1)
+    cfg = PlannerConfig(pallas_min_postings=1, allow_pallas=True)
     from repro.engine import Planner
     eng2 = Engine(B=64, growth="const", planner=cfg)
     assert isinstance(eng2.planner, Planner)
     eng2.add_document([vocab[0], vocab[1]])
     r = eng2.execute(Query(terms=(vocab[0],), mode="ranked_tfidf"))
     assert r.backend == "pallas"
+
+
+def _pallas_winning_crossover():
+    from repro.engine.planner import CrossoverTable
+    return CrossoverTable(min_batch={
+        m: {"device": None, "pallas": 1}
+        for m in ("conjunctive", "ranked_tfidf", "bm25")})
+
+
+@pytest.mark.parametrize("mode", ["conjunctive", "ranked_tfidf", "bm25"])
+@pytest.mark.parametrize("crossover", [False, True])
+def test_default_planner_never_routes_to_pallas(mode, crossover):
+    """The Pallas flavour does not compile for a TPU, so the default config
+    must route no batch and no single query there: not on candidate
+    volume, not on a crossover table in which pallas wins everything."""
+    from repro.engine import Planner
+    cfg = PlannerConfig(crossover=_pallas_winning_crossover()
+                        if crossover else None)
+    p = Planner(cfg)
+    q = Query(terms=("a", "b"), mode=mode)
+    for ft in (1, 2048, 10 ** 7):
+        stats = [TermStats(ft=ft, nblocks=ft // 32 + 1)] * 2
+        for batch in (1, 2, 4, 32, 256):
+            for device_capable in (True, False):
+                d = p.plan(q, batch, stats, device_capable=device_capable)
+                assert d.backend != "pallas", (ft, batch, d)
+    # opting in brings both pallas rules back
+    p_in = Planner(PlannerConfig(crossover=cfg.crossover, allow_pallas=True,
+                                 pallas_min_postings=1))
+    stats = [TermStats(ft=4096, nblocks=129)] * 2
+    assert p_in.plan(q, 1, stats, device_capable=True).backend == "pallas"
+
+
+@pytest.mark.parametrize("how", ["query", "engine"])
+def test_forced_pallas_still_routes_to_pallas(small_docs, how):
+    vocab, docs = small_docs
+    eng = Engine(B=64, growth="const",
+                 force_backend="pallas" if how == "engine" else None)
+    for d in docs[:40]:
+        eng.add_document(d)
+    q = Query(terms=(vocab[0], vocab[1]), mode="ranked_tfidf",
+              backend="pallas" if how == "query" else None)
+    assert eng.execute(q).backend == "pallas"
+    assert all(r.backend == "pallas" for r in eng.execute_many([q] * 6))
 
 
 def test_force_backend_knob(small_docs):

@@ -314,7 +314,8 @@ def test_crossover_table_derivation():
 
 def test_planner_routes_by_measured_crossover():
     t = CrossoverTable.from_rows(_rows())
-    p = Planner(PlannerConfig(crossover=t, pallas_min_postings=10 ** 9))
+    p = Planner(PlannerConfig(crossover=t, pallas_min_postings=10 ** 9,
+                              allow_pallas=True))
     stats = [TermStats(ft=50, nblocks=2)]
     q = Query(terms=("a",), mode="bm25", k=10)
     assert p.plan(q, 8, stats, device_capable=True).backend == "device"

@@ -307,7 +307,7 @@ def test_planner_prefers_tiered_once_published(stream_docs):
     eng.lifecycle.freeze(blocking=True)
     after = eng.execute(EQuery(terms=(vocab[120],), mode="conjunctive"))
     assert after.backend == "tiered"
-    # batches still go to the device image, volume still to pallas
+    # batches still go to the device image
     batch = [EQuery(terms=(vocab[i], vocab[i + 1]), mode="ranked_tfidf")
              for i in range(6)]
     assert all(r.backend == "device" for r in eng.execute_many(batch))
