@@ -68,6 +68,9 @@ for i, d in enumerate(SyntheticCorpus(CorpusSpec(
         ops.append(("query", Query(terms=tuple(sample[:2]),
                                    mode="bm25", k=3)))
 tickets = svc.run_stream(ops)
-print(f"\nserved {len(tickets)} queries interleaved with 200 ingests: "
-      f"{svc.latency_summary()}")
+print(f"\nserved {len(tickets)} queries interleaved with 200 ingests; "
+      f"result cache {svc.cache_stats()}")
+for name, (n, total_s, self_s) in sorted(eng.stats().spans.items()):
+    print(f"  span {name}: {n} x, {1e3 * total_s:.1f} ms total, "
+          f"{1e3 * self_s:.1f} ms self")
 print(f"final stats: {eng.stats()}")

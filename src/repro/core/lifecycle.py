@@ -46,7 +46,6 @@ evaluated over chained static+dynamic positional cursors are exact.
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 from dataclasses import dataclass
 
@@ -344,17 +343,19 @@ class FreezeManager:
             eng.collate_now()       # shared freeze point with the device tier
             snapshot = eng.index.clone()
             epoch = self.epoch + 1
-            t0 = time.perf_counter()
 
             def work():
                 try:
-                    static = StaticIndex.freeze(snapshot, self.policy.codec)
+                    with eng.spans.span("repro.tier.encode",
+                                        epoch=epoch) as encode:
+                        static = StaticIndex.freeze(snapshot,
+                                                    self.policy.codec)
                     static.epoch = epoch
                     tier = StaticTier(index=static,
                                       num_docs=snapshot.num_docs,
                                       num_postings=snapshot.num_postings,
                                       epoch=epoch,
-                                      encode_s=time.perf_counter() - t0,
+                                      encode_s=encode.seconds,
                                       compacted=len(snapshot.tombstones))
                     # atomic publish: ONE reference assignment of an
                     # immutable payload — epoch/freezes/last_freeze_s are

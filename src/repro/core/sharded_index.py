@@ -36,6 +36,7 @@ Two layers live here:
 
 from __future__ import annotations
 
+import contextvars
 from dataclasses import dataclass
 from functools import partial
 
@@ -43,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..obs import SpanTable, merge
 from .device_index import DeviceIndex, decode_blocks, query_step
 
 
@@ -291,6 +293,9 @@ class ShardedEngine:
         for e in self.engines:
             if getattr(e, "lifecycle", None) is not None:
                 self.coordinator.register(e.lifecycle)
+        # fleet-level spans (a QueryService flush over the fleet); every
+        # shard engine keeps its own table, and stats() sums them all
+        self.spans = SpanTable()
         self._pool = None
         if parallel and self.num_shards > 1:
             from concurrent.futures import ThreadPoolExecutor
@@ -583,8 +588,12 @@ class ShardedEngine:
 
         from ..engine.types import QueryResult
         if self._pool is not None:
+            # each shard runs in a copy of the caller's context, so its
+            # spans carry the caller's flush id (repro.obs)
+            ctxs = [contextvars.copy_context() for _ in self.engines]
             per_shard = list(self._pool.map(
-                lambda e: e.execute_many(queries), self.engines))
+                lambda e, c: c.run(e.execute_many, queries),
+                self.engines, ctxs))
         else:
             per_shard = [e.execute_many(queries) for e in self.engines]
         out = []
@@ -645,8 +654,10 @@ class ShardedEngine:
         ``[e.stats() for e in engine.engines]``."""
         from ..engine.types import EngineStats
         agg = EngineStats()
+        shard_spans = []
         for e in self.engines:
             s = e.stats()
+            shard_spans.append(s.spans)
             agg.deleted_docs += s.deleted_docs
             agg.tombstones_compacted += s.tombstones_compacted
             agg.num_postings += s.num_postings
@@ -664,6 +675,7 @@ class ShardedEngine:
             agg.freezes += s.freezes
             for k, v in s.by_backend.items():
                 agg.by_backend[k] = agg.by_backend.get(k, 0) + v
+        agg.spans = merge(self.spans.snapshot(), *shard_spans)
         agg.num_docs = self.num_docs
         agg.vocab_size = len(self._ft)
         agg.tier_epoch = self.coordinator.epoch

@@ -98,6 +98,7 @@ class ResidentImageManager:
         self.frozen_uploads = 0                        # resident-image uploads
         self.batches_served = 0                        # fused launches
         self.launch_buckets: Counter = Counter()
+        self._bucket_ix: dict = {}      # writer_only — key -> first-seen #
 
     # ------------------------------------------------------------------
     # image lifecycle
@@ -108,8 +109,9 @@ class ResidentImageManager:
         rebase the delta to empty.  Called by ``Engine.collate_now`` — the
         ONLY point at which the full block array is re-uploaded."""
         eng = self.engine
-        self._frozen_raw = build_device_image(eng.index, eng.vocab)
-        self._baseline = capture_delta_baseline(eng.index, eng.vocab)
+        with eng.spans.span("repro.device.upload"):
+            self._frozen_raw = build_device_image(eng.index, eng.vocab)
+            self._baseline = capture_delta_baseline(eng.index, eng.vocab)
         self._frozen_mb = _pow2(int(self._frozen_raw.term_nblk.max())
                                 if eng.vocab else 1)
         self._frozen = None        # stale metadata: rebuild from _frozen_raw
@@ -153,12 +155,19 @@ class ResidentImageManager:
         Returns True if anything was rebuilt.  ``collate()`` runs here only
         when the compaction policy trips (projected delta past the
         fragmentation threshold); below it, this is the honest
-        immediate-access path for the device backends.
+        immediate-access path for the device backends.  A
+        ``repro.device.refresh`` span whose ``rebuilt`` id says which.
         """
+        eng = self.engine
+        stale = self._synced_version != eng.version
+        with eng.spans.span("repro.device.refresh", rebuilt=int(stale)):
+            if stale:
+                self._rebuild()
+        return stale
+
+    def _rebuild(self) -> None:
         import jax.numpy as jnp
         eng = self.engine
-        if self._synced_version == eng.version:
-            return False
         if not eng.device_capable:
             raise UnsupportedQueryError(
                 "device images need a Const-mode doc-level index")
@@ -243,7 +252,6 @@ class ResidentImageManager:
             self._avg_stat = jnp.float32(stats.avg_doclen)
         self._synced_version = eng.version
         eng.stats_counters.delta_refreshes += 1
-        return True
 
     @property
     def delta_blocks(self) -> int:
@@ -271,10 +279,69 @@ def fused_execute(engine, resident: ResidentImageManager,
     """Answer one (mode, k) query group with a single fused launch over the
     resident images.  Shared by the device (flavor="ref") and pallas
     (flavor="pallas") backends — identical math, one resident state.
-    ``interpret`` applies to the pallas flavour only."""
+    ``interpret`` applies to the pallas flavour only.  Its stages are the
+    spans ``repro.device.prep``, ``.launch``, ``.wait`` (for the program
+    to finish), ``.readback`` (the pull of its results) and ``.unpack``."""
+    import jax
+    spans = engine.spans
+    results = [QueryResult(np.zeros(0, np.int64),
+                           None if mode == "conjunctive"
+                           else np.zeros(0, np.float64), name)
+               for _ in batch]
+    with spans.span("repro.device.prep", mode=mode):
+        prep = _prep_launch(engine, resident, batch, mode)
+    if prep is None:
+        return results
+    live, qt, qm, caps = prep
+    frozen, delta = resident.images
+    key = (mode, k, int(qt.shape[0]), int(qt.shape[1]), caps,
+           int(frozen.blocks.shape[0]), int(delta.blocks.shape[0]),
+           int(frozen.term_slot.shape[0]), int(frozen.num_docs),
+           resident._alive is not None)
+    new_shape = key not in resident._bucket_ix
+    if new_shape:
+        resident._bucket_ix[key] = len(resident._bucket_ix)
+    spec = registry.get("fused_query")
+    with spans.span("repro.device.launch", mode=mode,
+                    bucket=resident._bucket_ix[key],
+                    new_shape=int(new_shape)):
+        out = spec.fn(resident.images, qt, qm, mode=mode, k=k,
+                      max_blocks=caps,
+                      doclens=resident._doclens if mode == "bm25" else None,
+                      n_stat=resident._n_stat, avg_stat=resident._avg_stat,
+                      alive=resident._alive, flavor=flavor,
+                      interpret=interpret)
+    resident.batches_served += 1
+    resident.launch_buckets[key] += 1
+    with spans.span("repro.device.wait", mode=mode):
+        jax.block_until_ready(out)
+    N = engine.index.num_docs
+    if mode == "conjunctive":
+        with spans.span("repro.device.readback", mode=mode):
+            matches = np.asarray(out)
+        with spans.span("repro.device.unpack", mode=mode):
+            for row, i in enumerate(live):
+                d = np.flatnonzero(matches[row, 1:]) + 1
+                results[i] = QueryResult(d[d <= N].astype(np.int64), None,
+                                         name)
+        return results
+    with spans.span("repro.device.readback", mode=mode):
+        alld, alls = np.asarray(out[0]), np.asarray(out[1])
+    with spans.span("repro.device.unpack", mode=mode):
+        for row, i in enumerate(live):
+            d, s = alld[row], alls[row]
+            keep = (s > 0) & (d > 0)   # already in canonical order (top_k)
+            results[i] = QueryResult(d[keep].astype(np.int64),
+                                     s[keep].astype(np.float64), name)
+    return results
+
+
+def _prep_launch(eng, resident: ResidentImageManager, batch: list[Query],
+                 mode: str):
+    """Host prep of one launch: term ids, the padded ``qt``/``qm`` rows
+    (uploaded) and the per-image packed pool caps.  None when no query of
+    the batch needs the device."""
     import jax.numpy as jnp
-    eng = engine
-    N = eng.index.num_docs
     # term-id resolution; conjunctive queries with an unknown term are
     # decided (empty) without touching the device
     tids: list[list[int] | None] = []
@@ -285,12 +352,8 @@ def fused_execute(engine, resident: ResidentImageManager,
         else:
             tids.append([i for i in ids if i is not None])
     live = [i for i, ids in enumerate(tids) if ids]
-    results = [QueryResult(np.zeros(0, np.int64),
-                           None if mode == "conjunctive"
-                           else np.zeros(0, np.float64), name)
-               for _ in batch]
     if not live:
-        return results
+        return None
     Qn = _pow2(len(live))
     T = _pow2(max(len(tids[i]) for i in live), floor=4)
     qt = np.zeros((Qn, T), np.int32)
@@ -310,31 +373,7 @@ def fused_execute(engine, resident: ResidentImageManager,
         tot = max((sum(int(nblk[t]) for t in tids[i] if t < V)
                    for i in live), default=0)
         caps.append(_pow2(max(tot, 1), floor=8))
-    spec = registry.get("fused_query")
-    out = spec.fn(resident.images, qt, qm, mode=mode, k=k,
-                  max_blocks=tuple(caps),
-                  doclens=resident._doclens if mode == "bm25" else None,
-                  n_stat=resident._n_stat, avg_stat=resident._avg_stat,
-                  alive=resident._alive, flavor=flavor, interpret=interpret)
-    resident.batches_served += 1
-    frozen, delta = resident.images
-    resident.launch_buckets[(
-        mode, k, Qn, T, tuple(caps), int(frozen.blocks.shape[0]),
-        int(delta.blocks.shape[0]), int(frozen.term_slot.shape[0]),
-        int(frozen.num_docs), resident._alive is not None)] += 1
-    if mode == "conjunctive":
-        matches = np.asarray(out)
-        for row, i in enumerate(live):
-            d = np.flatnonzero(matches[row, 1:]) + 1
-            results[i] = QueryResult(d[d <= N].astype(np.int64), None, name)
-        return results
-    alld, alls = np.asarray(out[0]), np.asarray(out[1])
-    for row, i in enumerate(live):
-        d, s = alld[row], alls[row]
-        keep = (s > 0) & (d > 0)   # already in canonical order from top_k
-        results[i] = QueryResult(d[keep].astype(np.int64),
-                                 s[keep].astype(np.float64), name)
-    return results
+    return live, qt, qm, tuple(caps)
 
 
 class DeviceBackend(Backend):

@@ -9,8 +9,6 @@ scaling PRs (async ingest, caching, multi-backend fusion) plug in here.
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from ..core.collate import collate
@@ -18,6 +16,7 @@ from ..core.index import DynamicIndex, group_occurrences
 from ..core.lifecycle import FreezeManager, FreezePolicy
 from ..core.prepare import prepare_batch
 from ..core.query import CollectionStats, TermStats
+from ..obs import SpanTable
 from .backends import (
     HostBackend,
     PallasBackend,
@@ -131,6 +130,9 @@ class Engine:
         # batch ingest (entries are None between batches)
         self._group_scratch: list = []
         self.stats_counters = EngineStats()
+        # the program's own timings (repro.obs): every layer of this engine
+        # records its spans here; stats() exports them
+        self.spans = SpanTable()
         # ONE resident device-image manager shared by the device and pallas
         # backends: a mixed stream pays for at most one frozen upload and
         # one delta rebuild per engine version
@@ -286,7 +288,16 @@ class Engine:
     def add_document(self, terms) -> int:
         """Ingest one document; it is queryable on every backend the moment
         this returns (device backends refresh their delta lazily)."""
-        t0 = time.perf_counter()
+        with self.spans.span("repro.engine.ingest"):
+            d = self._add_one(terms)
+        sc = self.stats_counters
+        sc.ingest_docs += 1
+        sc.ingest_batches += 1
+        if self.lifecycle is not None:
+            self.lifecycle.maybe_freeze()
+        return d
+
+    def _add_one(self, terms) -> int:
         d = self.index.add_document(terms)
         tbs = [t.encode() if isinstance(t, str) else t for t in terms]
         entry: list[tuple[int, int]] = []
@@ -310,12 +321,6 @@ class Engine:
         self._doc_tids.append(entry)
         self._doclens.append(len(terms))
         self.version += 1
-        sc = self.stats_counters
-        sc.ingest_docs += 1
-        sc.ingest_batches += 1
-        sc.ingest_time_s += time.perf_counter() - t0
-        if self.lifecycle is not None:
-            self.lifecycle.maybe_freeze()
         return d
 
     def add_documents(self, docs) -> list[int]:
@@ -338,7 +343,16 @@ class Engine:
         whole batch already ingested rather than mid-stream — tier contents
         at any horizon are identical either way.
         """
-        t0 = time.perf_counter()
+        with self.spans.span("repro.engine.ingest"):
+            dids = self._add_batch(docs)
+        sc = self.stats_counters
+        sc.ingest_docs += len(dids)
+        sc.ingest_batches += 1
+        if self.lifecycle is not None:
+            self.lifecycle.maybe_freeze()
+        return dids
+
+    def _add_batch(self, docs) -> list[int]:
         word = self.index.word_level
         prepared = prepare_batch(docs, word)
         tid_of = self._tid
@@ -415,12 +429,6 @@ class Engine:
                 by_tid[tid] = None
             dids = list(range(base + 1, d + 1))
         self.version += len(prepared)
-        sc = self.stats_counters
-        sc.ingest_docs += len(prepared)
-        sc.ingest_batches += 1
-        sc.ingest_time_s += time.perf_counter() - t0
-        if self.lifecycle is not None:
-            self.lifecycle.maybe_freeze()
         return dids
 
     def delete_document(self, docid: int) -> list[tuple[int, int]]:
@@ -465,10 +473,11 @@ class Engine:
         rebases to empty.  Queries never require this — the delta keeps the
         device backend current — but a periodic collation keeps the delta
         (and host cache locality) small."""
-        self.index = collate(self.index)
-        self.stats_counters.collations += 1
-        if self.device_capable:
-            self.resident.freeze()
+        with self.spans.span("repro.engine.collate"):
+            self.index = collate(self.index)
+            self.stats_counters.collations += 1
+            if self.device_capable:
+                self.resident.freeze()
 
     def _maybe_auto_collate(self) -> None:
         frac = self.auto_collate_delta_frac
@@ -489,8 +498,10 @@ class Engine:
         """Plan and run a batch; results align with ``queries``."""
         if not queries:
             return []
-        t0 = time.perf_counter()
-        self._maybe_auto_collate()
+        with self.spans.span("repro.engine.execute", queries=len(queries)):
+            return self._execute_many(queries)
+
+    def _plan(self, queries: list[Query]) -> list:
         plans = []
         for q in queries:
             # planning reads only the engine's O(1) f_t counters — never the
@@ -506,6 +517,12 @@ class Engine:
                 # additionally need word positions (as does the host path)
                 tiered_capable=(self.index.word_level
                                 if q.mode in POSITIONAL_MODES else True)))
+        return plans
+
+    def _execute_many(self, queries: list[Query]) -> list[QueryResult]:
+        self._maybe_auto_collate()
+        with self.spans.span("repro.engine.plan"):
+            plans = self._plan(queries)
         out: list[QueryResult | None] = [None] * len(queries)
         by_backend: dict[str, list[int]] = {}
         for i, p in enumerate(plans):
@@ -518,7 +535,6 @@ class Engine:
                 out[i] = r
         self.stats_counters.queries += len(queries)
         self.stats_counters.query_batches += 1
-        self.stats_counters.query_time_s += time.perf_counter() - t0
         for p in plans:
             bb = self.stats_counters.by_backend
             bb[p.backend] = bb.get(p.backend, 0) + 1
@@ -557,6 +573,9 @@ class Engine:
 
     def stats(self) -> EngineStats:
         s = self.stats_counters
+        s.spans = self.spans.snapshot()
+        s.query_time_s = self.spans.total("repro.engine.execute")
+        s.ingest_time_s = self.spans.total("repro.engine.ingest")
         s.num_docs = self.index.num_docs
         s.deleted_docs = len(self.index.tombstones)
         s.num_postings = self.index.num_postings

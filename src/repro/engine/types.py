@@ -95,13 +95,13 @@ class EngineStats:
     vocab_size: int = 0
     queries: int = 0
     query_batches: int = 0    # execute_many calls (latency denominator)
-    query_time_s: float = 0.0  # wall-clock inside execute_many (plan+run)
+    query_time_s: float = 0.0  # total of the repro.engine.execute spans
     ingest_docs: int = 0      # documents ingested (add_document(s))
     ingest_batches: int = 0   # ingest calls (mirror of query_batches: a
     #                           single add_document counts as a batch of 1)
-    ingest_time_s: float = 0.0  # wall-clock inside ingest (tokenize+append
-    #                             +bookkeeping; excludes queue wait in the
-    #                             pipelined path — writer-thread time only)
+    ingest_time_s: float = 0.0  # total of the repro.engine.ingest spans
+    #                             (tokenize+append+bookkeeping; excludes
+    #                             queue wait in the pipelined path)
     collations: int = 0
     delta_refreshes: int = 0
     delta_compactions: int = 0  # refreshes that hit the fragmentation
@@ -113,3 +113,5 @@ class EngineStats:
     #                           sum over shards, bumping on any tier swap)
     num_shards: int = 0       # 0 = single engine; >0 = sharded composite
     by_backend: dict = field(default_factory=dict)
+    # the engine's span table (repro.obs): {name: [count, total_s, self_s]}
+    spans: dict = field(default_factory=dict)

@@ -150,8 +150,9 @@ def fused_query(images, qterms, qmask, *, mode: str = "ranked_tfidf",
         max_blocks = (max_blocks,) * len(images)
     Ns = (jnp.float32(cap) if n_stat is None
           else n_stat.astype(jnp.float32))
-    parts = tuple(_prep_image(img, qterms, qmask, Ns, mb, mode)
-                  for img, mb in zip(images, max_blocks))
+    with jax.named_scope("gather"):
+        parts = tuple(_prep_image(img, qterms, qmask, Ns, mb, mode)
+                      for img, mb in zip(images, max_blocks))
     nterms = qmask.sum(axis=1).astype(jnp.int32)
     if mode == "bm25":
         avgdl = (jnp.maximum(doclens[1:].sum() / Ns, 1e-9)

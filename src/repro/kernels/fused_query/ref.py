@@ -44,6 +44,10 @@ absolute cumsum of leading b-gaps) and the delta's captured tail state for
 delta segments (first value = d-gap from ``lastd0``, later blocks chain
 b-gaps from ``dnum0`` — see ``core.device_index.DeltaIndex``).
 
+The stages carry ``jax.named_scope`` names — ``decode``, ``accumulate``
+and ``topk`` here, ``gather`` in ``ops._prep_image`` — so a device trace
+or the lowered HLO says which stage each operation belongs to.
+
 Aggregation is a *dense scatter over the docid capacity*: every decoded
 posting adds its weight (or hit count) into a (TQ, cap+1) accumulator, and
 top-k runs over that axis — docids are the top-k indices themselves, and
@@ -270,34 +274,41 @@ def fused_tile(parts, nterms, doclens, bm25_norm, *, mode: str, k: int,
     if mode == "conjunctive":
         hits = jnp.zeros((TQ, cap + 1), jnp.int32)
         for part in parts:
-            docid, _f, valid, _w = _part_postings(part, F)
-            hits = _scatter_add(hits, docid.reshape(TQ, -1),
-                                valid.reshape(TQ, -1).astype(jnp.int32))
-        matches = (hits == nterms[:, None]) & (nterms[:, None] > 0)
-        if alive is not None:
-            matches = matches & _unpack_alive(alive, cap)[None, :]
-        return matches.at[:, 0].set(False)
+            with jax.named_scope("decode"):
+                docid, _f, valid, _w = _part_postings(part, F)
+            with jax.named_scope("accumulate"):
+                hits = _scatter_add(hits, docid.reshape(TQ, -1),
+                                    valid.reshape(TQ, -1).astype(jnp.int32))
+        with jax.named_scope("topk"):
+            matches = (hits == nterms[:, None]) & (nterms[:, None] > 0)
+            if alive is not None:
+                matches = matches & _unpack_alive(alive, cap)[None, :]
+            return matches.at[:, 0].set(False)
     score = jnp.zeros((TQ, cap + 1), jnp.float32)
     for part in parts:
-        docid, f, valid, widf = _part_postings(part, F)
-        fv = jnp.where(valid, f, 0).astype(jnp.float32)
-        if mode == "bm25":
-            dl = doclens[docid]                        # (TQ, PB, B)
-            tf = (fv * (BM25_K1 + 1.0)) / (
-                fv + bm25_norm[0] + bm25_norm[1] * dl)
-            w = tf * widf[:, :, None]
-        else:
-            w = precise_log1p(fv) * widf[:, :, None]
-        w = jnp.where(valid, w, 0.0)
-        score = _scatter_add(score, docid.reshape(TQ, -1),
-                             w.reshape(TQ, -1))
-    if alive is not None:
-        # mask by select, not multiply: a fully-deleted term's padded idf
-        # could be ±inf, and inf * 0 would poison the accumulator with nan
-        score = jnp.where(_unpack_alive(alive, cap)[None, :], score, 0.0)
-    # docids are the accumulator indices: top_k ties prefer the smaller
-    # index, i.e. the smaller docid — canonical order for free.  Absent
-    # docids hold exactly 0.0 and every real match scores > 0 (idf > 0),
-    # so the caller's s > 0 filter drops them.
-    top_s, top_d = jax.lax.top_k(score, min(k, cap + 1))
-    return top_d.astype(jnp.int32), top_s
+        with jax.named_scope("decode"):
+            docid, f, valid, widf = _part_postings(part, F)
+        with jax.named_scope("accumulate"):
+            fv = jnp.where(valid, f, 0).astype(jnp.float32)
+            if mode == "bm25":
+                dl = doclens[docid]                    # (TQ, PB, B)
+                tf = (fv * (BM25_K1 + 1.0)) / (
+                    fv + bm25_norm[0] + bm25_norm[1] * dl)
+                w = tf * widf[:, :, None]
+            else:
+                w = precise_log1p(fv) * widf[:, :, None]
+            w = jnp.where(valid, w, 0.0)
+            score = _scatter_add(score, docid.reshape(TQ, -1),
+                                 w.reshape(TQ, -1))
+    with jax.named_scope("topk"):
+        if alive is not None:
+            # mask by select, not multiply: a fully-deleted term's padded
+            # idf could be ±inf, and inf * 0 would poison the accumulator
+            score = jnp.where(_unpack_alive(alive, cap)[None, :], score,
+                              0.0)
+        # docids are the accumulator indices: top_k ties prefer the smaller
+        # index, i.e. the smaller docid — canonical order for free.  Absent
+        # docids hold exactly 0.0 and every real match scores > 0 (idf >
+        # 0), so the caller's s > 0 filter drops them.
+        top_s, top_d = jax.lax.top_k(score, min(k, cap + 1))
+        return top_d.astype(jnp.int32), top_s

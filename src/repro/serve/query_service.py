@@ -27,9 +27,8 @@ bounded by ``cache_size`` (0 disables caching entirely).
 
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 from ..engine.types import Query, QueryResult
 
@@ -40,8 +39,6 @@ class Ticket:
 
     query: Query
     result: QueryResult | None = None
-    submitted_at: float = field(default_factory=time.perf_counter)
-    latency_s: float | None = None
 
     @property
     def done(self) -> bool:
@@ -51,15 +48,19 @@ class Ticket:
 class QueryService:
     """Batching executor over an :class:`~repro.engine.Engine` (or a
     :class:`~repro.core.sharded_index.ShardedEngine` — anything with
-    ``add_document``/``execute_many``)."""
+    ``add_document``/``execute_many`` and a ``spans`` table).
+
+    Each non-empty ``flush`` is a ``repro.service.flush`` span in the
+    engine's span table (``engine.stats().spans``, :mod:`repro.obs`), with
+    ids ``flush`` (a sequence number every span nested in it carries),
+    ``tickets`` and ``queries`` (distinct queries)."""
 
     def __init__(self, engine, max_batch: int = 32, cache_size: int = 256,
                  pipelined: bool = False, pipeline_queue: int = 8):
         self.engine = engine
         self.max_batch = max_batch
         self._pending: list[Ticket] = []                # writer_only
-        self.query_latencies: list[float] = []
-        self.ingest_latencies: list[float] = []
+        self.flushes = 0                                # writer_only
         self.cache_size = cache_size
         self._cache: OrderedDict[tuple, QueryResult] \
             = OrderedDict()                             # writer_only
@@ -130,26 +131,18 @@ class QueryService:
         requires a query to see documents ingested before its submission.
         On the pipelined path this enqueues and returns the docid
         immediately; visibility is settled by ``flush``'s drain."""
-        t0 = time.perf_counter()
         if self.pipeline is not None:
-            d = self.pipeline.submit([terms])[0]
-        else:
-            d = self.engine.add_document(terms)
-        self.ingest_latencies.append(time.perf_counter() - t0)
-        return d
+            return self.pipeline.submit([terms])[0]
+        return self.engine.add_document(terms)
 
     def ingest_batch(self, docs) -> list[int]:
         """Ingest a batch of documents in one write-path pass (one chain-tail
         lookup and one contiguous encode per distinct term — see
         ``DynamicIndex.add_documents``).  Same flush semantics as
         ``ingest``: pending queries legally miss these documents."""
-        t0 = time.perf_counter()
         if self.pipeline is not None:
-            dids = self.pipeline.submit(docs)
-        else:
-            dids = self.engine.add_documents(docs)
-        self.ingest_latencies.append(time.perf_counter() - t0)
-        return dids
+            return self.pipeline.submit(docs)
+        return self.engine.add_documents(docs)
 
     def delete(self, docid: int) -> None:
         """Tombstone one document.  Pending queries were submitted while it
@@ -161,9 +154,7 @@ class QueryService:
         result under the old version unaddressable (invalidation is free,
         same as ingest)."""
         self.flush()
-        t0 = time.perf_counter()
         self.engine.delete_document(docid)
-        self.ingest_latencies.append(time.perf_counter() - t0)
 
     def update(self, docid: int, terms) -> int:
         """Revise a document: tombstone ``docid``, ingest ``terms`` as a new
@@ -171,10 +162,7 @@ class QueryService:
         ``delete`` — they must see the pre-revision state they were
         submitted against."""
         self.flush()
-        t0 = time.perf_counter()
-        d = self.engine.update_document(docid, terms)
-        self.ingest_latencies.append(time.perf_counter() - t0)
-        return d
+        return self.engine.update_document(docid, terms)
 
     # -- querying -------------------------------------------------------
 
@@ -199,11 +187,24 @@ class QueryService:
         one barrier honors every ticket's high-water mark at once, and
         after it the writer threads are idle, making the cache keys below
         (engine version) stable and the engine safe to fan out over."""
-        if self.pipeline is not None:
-            self.pipeline.drain()
         batch, self._pending = self._pending, []
         if not batch:
+            if self.pipeline is not None:
+                self.pipeline.drain()
             return []
+        self.flushes += 1
+        with self.engine.spans.span("repro.service.flush",
+                                    flush=self.flushes,
+                                    tickets=len(batch),
+                                    queries=len({t.query for t in batch})):
+            if self.pipeline is not None:
+                self.pipeline.drain()
+            self._execute(batch)
+        return batch
+
+    def _execute(self, batch: list[Ticket]) -> None:
+        """Fill every ticket of ``batch``: cache hits first, then one
+        engine batch of the distinct misses."""
         # the key is computed ONCE per ticket and reused at store time: a
         # background freeze may bump lifecycle.epoch while execute_many
         # runs, and recomputing the key there would file the result under
@@ -237,11 +238,6 @@ class QueryService:
                     self._cache[key] = self._copy_result(r)
                     while len(self._cache) > self.cache_size:
                         self._cache.popitem(last=False)
-        now = time.perf_counter()
-        for t in batch:
-            t.latency_s = now - t.submitted_at
-            self.query_latencies.append(t.latency_s)
-        return batch
 
     def query(self, query: Query) -> QueryResult:
         """Synchronous single query (flushes anything already queued so
@@ -290,20 +286,3 @@ class QueryService:
                 raise ValueError(f"unknown op {kind!r}")
         self.flush()
         return tickets
-
-    # -- observability ---------------------------------------------------
-
-    def latency_summary(self) -> dict:
-        import numpy as np
-        out = {}
-        for name, xs in (("query", self.query_latencies),
-                         ("ingest", self.ingest_latencies)):
-            if xs:
-                a = np.asarray(xs)
-                out[name] = {"n": len(a), "mean_us": float(a.mean() * 1e6),
-                             "p99_us": float(np.quantile(a, 0.99) * 1e6)}
-        if self.cache_hits or self.cache_misses:
-            out["cache"] = {"hits": self.cache_hits,
-                            "misses": self.cache_misses,
-                            "entries": len(self._cache)}
-        return out

@@ -325,8 +325,11 @@ def test_query_service_immediate_access(small_docs):
     assert t1.done and t1 in tickets
     exp = Q.brute_conjunctive(eng.index, [vocab[0]])
     assert t1.result.docids.tolist() == exp.tolist()
-    summary = svc.latency_summary()
-    assert summary["query"]["n"] == 1 and summary["ingest"]["n"] == 21
+    spans = eng.stats().spans
+    assert spans["repro.engine.ingest"][0] == 21
+    assert spans["repro.service.flush"][0] == 1
+    assert spans["repro.engine.execute"][0] == 1
+    assert svc.flushes == 1
 
 
 def test_engine_adopts_existing_index(small_docs):

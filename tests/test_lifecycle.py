@@ -360,8 +360,12 @@ def test_query_cache_hits_and_invalidation(stream_docs):
     eng.lifecycle.freeze(blocking=True)
     svc.query(q)
     assert svc.cache_misses == 3
-    summary = svc.latency_summary()
-    assert summary["cache"]["hits"] == 2 and summary["cache"]["misses"] == 3
+    cache = svc.cache_stats()
+    assert cache["hits"] == 2 and cache["misses"] == 3
+    # a hit is served in the flush without an engine batch
+    spans = eng.stats().spans
+    assert spans["repro.service.flush"][0] == 5
+    assert spans["repro.engine.execute"][0] == 3
 
 
 def test_query_cache_immune_to_caller_mutation(stream_docs):
