@@ -362,7 +362,7 @@ def one_chip(args, phases: Phases) -> None:
     check("pallas" not in st.by_backend, "a query was served by pallas")
     check(mgr.batches_served > 0, "no fused batch was served")
     log(f"compiled launch buckets ({len(mgr.launch_buckets)}), as (mode, k, "
-        "Qn, T, packed caps, frozen blocks, delta blocks, vocab cap, doc "
+        "Qn, T, pool sizes, frozen blocks, delta blocks, vocab cap, doc "
         "cap, liveness mask): launches")
     for key, n in sorted(mgr.launch_buckets.items(), key=str):
         log(f"  {key}: {n}")

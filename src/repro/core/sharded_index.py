@@ -672,6 +672,8 @@ class ShardedEngine:
             agg.delta_refreshes += s.delta_refreshes
             agg.delta_compactions += s.delta_compactions
             agg.resident_uploads += s.resident_uploads
+            agg.pool_slots += s.pool_slots
+            agg.pool_blocks += s.pool_blocks
             agg.freezes += s.freezes
             for k, v in s.by_backend.items():
                 agg.by_backend[k] = agg.by_backend.get(k, 0) + v

@@ -71,7 +71,7 @@ class ResidentImageManager:
     record: ``frozen_uploads`` bumps only at freeze (collation) time while
     ``batches_served`` bumps per fused launch — steady-state serving shows
     many batches per upload.  ``launch_buckets`` counts launches per
-    compiled-program key (mode, k, Qn, T, per-image packed caps, frozen and
+    compiled-program key (mode, k, Qn, T, per-image pool sizes, frozen and
     delta block counts, vocab and doc capacity, liveness mask or not): the
     number of distinct keys is the number of programs serving compiled.
     """
@@ -221,7 +221,7 @@ class ResidentImageManager:
         self._delta_mb = _pow2(int(delta.term_nblk.max())
                                if delta.term_nblk.shape[0] else 1)
         # host copy of both images' per-term chain sizes: fused_execute
-        # sizes each launch's packed block pool from the batch's actual
+        # sizes each launch's flat block pool from the batch's actual
         # chains (one small device→host pull per refresh, not per batch)
         self._nblk_np = (np.asarray(self._frozen.term_nblk),
                          np.asarray(delta.term_nblk))
@@ -292,7 +292,7 @@ def fused_execute(engine, resident: ResidentImageManager,
         prep = _prep_launch(engine, resident, batch, mode)
     if prep is None:
         return results
-    live, qt, qm, caps = prep
+    live, qt, qm, caps, owned = prep
     frozen, delta = resident.images
     key = (mode, k, int(qt.shape[0]), int(qt.shape[1]), caps,
            int(frozen.blocks.shape[0]), int(delta.blocks.shape[0]),
@@ -313,6 +313,8 @@ def fused_execute(engine, resident: ResidentImageManager,
                       interpret=interpret)
     resident.batches_served += 1
     resident.launch_buckets[key] += 1
+    engine.stats_counters.pool_slots += sum(caps)
+    engine.stats_counters.pool_blocks += owned
     with spans.span("repro.device.wait", mode=mode):
         jax.block_until_ready(out)
     N = engine.index.num_docs
@@ -339,8 +341,9 @@ def fused_execute(engine, resident: ResidentImageManager,
 def _prep_launch(eng, resident: ResidentImageManager, batch: list[Query],
                  mode: str):
     """Host prep of one launch: term ids, the padded ``qt``/``qm`` rows
-    (uploaded) and the per-image packed pool caps.  None when no query of
-    the batch needs the device."""
+    (uploaded), the per-image flat pool sizes and the chain blocks the
+    launch owns over both images.  None when no query of the batch needs
+    the device."""
     import jax.numpy as jnp
     # term-id resolution; conjunctive queries with an unknown term are
     # decided (empty) without touching the device
@@ -365,15 +368,14 @@ def _prep_launch(eng, resident: ResidentImageManager, batch: list[Query],
     qt, qm = jnp.asarray(qt), jnp.asarray(qm)
     if resident._nblk_np is None:
         resident.refresh()
-    # packed pool size per image: the batch's largest per-query total block
-    # count (pow2-bucketed so steady-state traffic reuses compiled programs)
-    caps = []
-    for nblk in resident._nblk_np:
-        V = nblk.shape[0]
-        tot = max((sum(int(nblk[t]) for t in tids[i] if t < V)
-                   for i in live), default=0)
-        caps.append(_pow2(max(tot, 1), floor=8))
-    return live, qt, qm, tuple(caps)
+    # flat pool size per image: pow2 of the chain blocks the launch's
+    # queries own there together (pow2-bucketed so steady-state traffic
+    # reuses compiled programs)
+    ids = np.fromiter((t for i in live for t in tids[i]), np.int64)
+    owned = [int(nblk[ids[ids < nblk.shape[0]]].sum())
+             for nblk in resident._nblk_np]
+    caps = tuple(_pow2(n, floor=8) for n in owned)
+    return live, qt, qm, caps, sum(owned)
 
 
 class DeviceBackend(Backend):

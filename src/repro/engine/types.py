@@ -107,6 +107,9 @@ class EngineStats:
     delta_compactions: int = 0  # refreshes that hit the fragmentation
     #                             threshold and collated instead
     resident_uploads: int = 0   # full device-image uploads (1 per freeze)
+    pool_slots: int = 0       # fused launches' flat pool slots, pads
+    #                           included (summed over both images)
+    pool_blocks: int = 0      # chain blocks those launches' queries own
     freezes: int = 0          # static-tier freezes completed (lifecycle)
     tier_epoch: int = 0       # epoch of the published static tier (for a
     #                           sharded fleet: the composite epoch — the

@@ -4,15 +4,16 @@
 device-resident images (typically the frozen :class:`DeviceIndex` plus the
 post-freeze :class:`DeltaIndex`) in a single launch per (mode, k) group:
 
-  1. *prep/gather* (XLA): per image, every query's live terms' chain
-     blocks are packed term-major into a (Q, PB_i, B) *part* whose slots
-     carry the owning term's segment id, docid-chaining bases and idf
-     weight — the uniform slot layout lets frozen and delta chains run
-     through identical segmented arithmetic (see ``ref.fused_tile``).
-     Each image keeps its OWN packed capacity PB_i (``max_blocks`` is a
-     per-image tuple, sized by the caller to the batch's longest per-query
-     block total): the delta suffix is typically a handful of blocks, and
-     packing means nobody pays for the vocabulary's longest chain;
+  1. *prep/gather* (XLA): per image, the chain blocks of every live
+     query's terms are packed query-major, then term-major, into one flat
+     (NS_i, B) *pool* whose slots carry their (query, term) segment id,
+     query row, docid-chaining bases and idf weight — the uniform slot
+     layout lets frozen and delta chains run through identical segmented
+     arithmetic (see ``ref.fused_tile``).  Each image keeps its OWN pool
+     size NS_i (``max_blocks`` is a per-image tuple, sized by the caller to
+     pow2 of the blocks the launch's queries own there): the delta suffix
+     is typically a handful of blocks, and no query pays for another's
+     longer chains;
   2. *fused compute*: decode → docids → score → top-k in one kernel
      (``flavor="pallas"``) or as the same math inline (``flavor="ref"``,
      the oracle the kernel is byte-compared against).
@@ -36,7 +37,7 @@ import jax.numpy as jnp
 from ...core.blockstore import H
 from ...core.device_index import DeltaIndex, precise_log1p
 from .. import registry
-from .kernel import DEFAULT_TQ, fused_query_kernel
+from .kernel import fused_query_kernel
 from .ref import BM25_B, BM25_K1, fused_tile
 
 #: Modes the fused kernel serves (positional modes need word positions,
@@ -45,75 +46,67 @@ FUSED_MODES = ("conjunctive", "ranked_tfidf", "bm25")
 
 
 def _prep_image(image, qterms, qmask, Ns, max_blocks: int, mode: str):
-    """Pack one image's chain blocks: (Q, PB, B) slots + per-slot metadata.
+    """Pack one image's chain blocks into a flat (NS, B) pool + per-slot
+    metadata.
 
-    Each query's live terms' actual chain blocks are packed term-major into
-    PB = ``max_blocks`` slots (the caller sizes PB to the batch's longest
-    per-query block total, NOT to T × the longest chain in the vocabulary —
-    a ~4–8× decode saving at bench scale).  Every slot carries its owning
-    term's segment id, chaining bases and idf weight, so the tile can run
-    segmented scans along the slot axis.
+    The chain blocks of every (query, term) pair — query-major, then
+    term-major — fill the first Σ nblk of NS = ``max_blocks`` slots (the
+    caller sizes NS to pow2 of that sum).  Every slot carries its pair's
+    segment id (unique per pair, so the tile's segmented scans chain
+    docids along the one slot axis), query row, chaining bases and idf
+    weight; the slots past the sum are pad slots that decode to nothing.
     """
     Q, T = qterms.shape
-    PB = max_blocks
+    NS = max_blocks
     B = image.blocks.shape[1]
     flat = qterms.reshape(-1)
-    slot = image.term_slot[flat].reshape(Q, T)
-    nblk = jnp.where(qmask, image.term_nblk[flat].reshape(Q, T), 0)
-    skip = image.term_skip[flat].reshape(Q, T)
-    nx = image.term_nx[flat].reshape(Q, T)
-    # term-major packing: slot s of query q belongs to the last term whose
-    # exclusive block-offset is <= s (empty terms yield no slots)
-    off = jnp.cumsum(nblk, axis=1) - nblk              # exclusive prefix
-    total = off[:, -1] + nblk[:, -1]
-    s = jnp.arange(PB, dtype=jnp.int32)[None, :]
-    t_of = (s[:, :, None] >= off[:, None, :]).sum(axis=2) - 1  # (Q, PB)
-    within = s - jnp.take_along_axis(off, t_of, axis=1)
-    valid = s < total[:, None]
-    slot_s = jnp.take_along_axis(slot, t_of, axis=1)
-    nblk_s = jnp.take_along_axis(nblk, t_of, axis=1)
-    bidx = jnp.where(valid, slot_s + within, 0)
-    gat = image.blocks[bidx.reshape(-1)].reshape(Q, PB, B)
+    nblk = jnp.where(qmask.reshape(-1), image.term_nblk[flat], 0)  # (Q*T,)
+    ends = jnp.cumsum(nblk)                            # inclusive offsets
+    s = jnp.arange(NS, dtype=jnp.int32)
+    # slot s belongs to the first pair whose chain ends past s (empty
+    # pairs own no slot); pad slots clip to the last pair
+    pair = jnp.minimum(jnp.searchsorted(ends, s, side="right"),
+                       Q * T - 1).astype(jnp.int32)
+    valid = s < ends[-1]
+    within = s - (ends - nblk)[pair]
+    bidx = jnp.where(valid, image.term_slot[flat][pair] + within, 0)
+    gat = image.blocks[bidx]
     is_head = within == 0
-    is_tail = within == nblk_s - 1
-    start = jnp.where(is_head, jnp.take_along_axis(skip, t_of, axis=1), H)
-    end = jnp.where(is_tail, jnp.take_along_axis(nx, t_of, axis=1), B)
+    is_tail = within == nblk[pair] - 1
+    start = jnp.where(is_head, image.term_skip[flat][pair], H)
+    end = jnp.where(is_tail, image.term_nx[flat][pair], B)
     end = jnp.where(valid, end, 0)
-    seg = jnp.where(valid, t_of, T)                    # pad slots: own seg
+    seg = jnp.where(valid, pair, Q * T)                # pad slots: own seg
+    qix = jnp.where(valid, pair // T, 0)
     if isinstance(image, DeltaIndex):
-        lastd0 = image.term_lastd0[flat].reshape(Q, T)
-        dnum0 = image.term_dnum0[flat].reshape(Q, T)
-        lastd0_s = jnp.take_along_axis(lastd0, t_of, axis=1)
-        dnum0_s = jnp.take_along_axis(dnum0, t_of, axis=1)
+        lastd0_s = image.term_lastd0[flat][pair]
+        dnum0_s = image.term_dnum0[flat][pair]
     else:
         # frozen segments: absolute chains — the -1 sentinel makes the tile
         # use the head block's own first gap as the b-gap base (pure cumsum)
-        lastd0_s = jnp.zeros((Q, PB), jnp.int32)
-        dnum0_s = jnp.full((Q, PB), -1, jnp.int32)
+        lastd0_s = jnp.zeros(NS, jnp.int32)
+        dnum0_s = jnp.full(NS, -1, jnp.int32)
     if mode == "conjunctive":
-        widf_s = jnp.zeros((Q, PB), jnp.float32)
+        widf_s = jnp.zeros(NS, jnp.float32)
     else:
         ft = jnp.maximum(image.term_ft[flat], 1).astype(jnp.float32)
         if mode == "bm25":
             widf = precise_log1p((Ns - ft + 0.5) / (ft + 0.5))
         else:
             widf = precise_log1p(Ns / ft)
-        widf = (widf * qmask.reshape(-1)).reshape(Q, T)
-        widf_s = jnp.where(valid, jnp.take_along_axis(widf, t_of, axis=1),
-                           0.0)
-    return (gat, start, end, seg, lastd0_s, dnum0_s, widf_s)
+        widf_s = jnp.where(valid, widf[pair], 0.0)
+    return (gat, start, end, seg, lastd0_s, dnum0_s, widf_s, qix)
 
 
 @partial(jax.jit, static_argnames=("mode", "k", "max_blocks", "flavor",
-                                   "interpret", "tq"))
+                                   "interpret"))
 def fused_query(images, qterms, qmask, *, mode: str = "ranked_tfidf",
                 k: int = 10, max_blocks: int | tuple = 64,
                 doclens: jnp.ndarray | None = None,
                 n_stat: jnp.ndarray | None = None,
                 avg_stat: jnp.ndarray | None = None,
                 alive: jnp.ndarray | None = None,
-                flavor: str = "ref", interpret: bool | None = None,
-                tq: int = DEFAULT_TQ):
+                flavor: str = "ref", interpret: bool | None = None):
     """One fused launch answering ``qterms``/``qmask`` against ``images``.
 
     Args:
@@ -122,10 +115,10 @@ def fused_query(images, qterms, qmask, *, mode: str = "ranked_tfidf",
         resident (frozen, delta) pair.
       qterms: (Q, T) i32 padded term ids; qmask: (Q, T) bool.
       mode: one of :data:`FUSED_MODES`.
-      max_blocks: per-image PACKED block capacity (slots per query, not
-        per term) — a tuple aligned with ``images`` (an int is broadcast
-        to every image); must cover the batch's largest per-query total
-        block count in that image.
+      max_blocks: per-image pool size (slots for the whole launch) — a
+        tuple aligned with ``images`` (an int is broadcast to every
+        image); must cover the chain blocks that all of the batch's
+        queries own in that image together.
       doclens: (cap+1,) f32 document lengths (bm25 only).
       n_stat / avg_stat: dynamic collection statistics (fleet-exact idf /
         avgdl); default to the image capacity / local doclens mean.
@@ -169,7 +162,7 @@ def fused_query(images, qterms, qmask, *, mode: str = "ranked_tfidf",
         if interpret is None:
             interpret = registry.default_interpret()
         return fused_query_kernel(parts, nterms, dl, norm, mode=mode, k=k,
-                                  F=F, cap=cap, tq=tq, interpret=interpret,
+                                  F=F, cap=cap, interpret=interpret,
                                   alive=alive_f)
     return fused_tile(parts, nterms, dl, norm, mode=mode, k=k, F=F, cap=cap,
                       alive=alive_f)
@@ -178,5 +171,5 @@ def fused_query(images, qterms, qmask, *, mode: str = "ranked_tfidf",
 registry.register(registry.KernelSpec(
     name="fused_query", fn=fused_query, modes=FUSED_MODES,
     description="single-launch decode→score→top-k over resident "
-                "frozen+delta images, query-major grid",
+                "frozen+delta images, one flat block pool per image",
     extras={"fused_modes": FUSED_MODES}))

@@ -1,16 +1,15 @@
 """Shared tile math for the fused decode→score→top-k query kernel.
 
-One function — :func:`fused_tile` — implements the whole per-query pipeline
+One function — :func:`fused_tile` — implements the whole query pipeline
 (Double-VByte decode, docid reconstruction across frozen+delta chain rows,
 weight accumulation, top-k / conjunctive matching) as straight-line jnp over
 fixed shapes.  Both flavours of the public op execute EXACTLY this function:
 
-* the reference flavour calls it once over the full query batch;
-* the Pallas flavour calls it inside a ``pallas_call`` body, one grid step
-  per ``tq`` queries (kernel.py).
+* the reference flavour calls it inline over the launch's pools;
+* the Pallas flavour calls it inside a ``pallas_call`` body, one
+  invocation over the same whole pools (kernel.py).
 
-Because the arithmetic is identical (same ops, same shapes up to the leading
-query-tile dimension, reductions only along per-query axes), the two
+Because the arithmetic is identical (same ops on the same shapes), the two
 flavours produce byte-identical results — the differential tests assert
 exact float equality, not tolerances.
 
@@ -30,33 +29,32 @@ vector ops (exactly what the VPU wants).  All shifts are ``pad``+``slice``
 materializes a wrapped copy plus a mask per level; the pad shifts in the
 fill value directly).
 
-The tile consumes a tuple of per-image *parts* — (frozen, delta), each with
-its own *packed* block pool: instead of a (T, MB) grid padded to the
-longest chain in the vocabulary (which decodes mostly empty slots — a
-per-term cap wastes ~4–8× at bench scale), prep packs each query's actual
-chain blocks term-major into PB = pow2(Σ_t nblk_t) slots, each slot
-carrying its term's segment id, docid-chaining bases and idf weight.
-Chaining then runs as *segmented* log-step scans along the slot axis
-(contiguous segments make plain Hillis–Steele with a same-segment guard
-exact).  Row bases ``lastd0``/``dnum0`` are (0, -1) for frozen segments
-(the -1 sentinel means "use the head block's first gap", reducing to the
-absolute cumsum of leading b-gaps) and the delta's captured tail state for
-delta segments (first value = d-gap from ``lastd0``, later blocks chain
-b-gaps from ``dnum0`` — see ``core.device_index.DeltaIndex``).
+The tile consumes a tuple of per-image *parts* — (frozen, delta), each one
+*flat pool* of chain blocks for the whole launch: prep packs every live
+query's actual chain blocks query-major, then term-major, into NS =
+pow2(Σ owned blocks) slots, so no slot pads for the vocabulary's longest
+chain or the batch's longest query.  Each slot carries its (query, term) segment id, its query row
+``qix``, docid-chaining bases and idf weight.  Chaining then runs as
+*segmented* log-step scans along the one slot axis (segments are contiguous
+and unique per (query, term), so plain Hillis–Steele with a same-segment
+guard is exact).  Row bases ``lastd0``/``dnum0`` are (0, -1) for frozen
+segments (the -1 sentinel means "use the head block's first gap", reducing
+to the absolute cumsum of leading b-gaps) and the delta's captured tail
+state for delta segments (first value = d-gap from ``lastd0``, later blocks
+chain b-gaps from ``dnum0`` — see ``core.device_index.DeltaIndex``).
 
 The stages carry ``jax.named_scope`` names — ``decode``, ``accumulate``
 and ``topk`` here, ``gather`` in ``ops._prep_image`` — so a device trace
 or the lowered HLO says which stage each operation belongs to.
 
 Aggregation is a *dense scatter over the docid capacity*: every decoded
-posting adds its weight (or hit count) into a (TQ, cap+1) accumulator, and
-top-k runs over that axis — docids are the top-k indices themselves, and
+posting adds its weight (or hit count) into a (Qn, cap+1) accumulator,
+through the flat index ``qix·(cap+1) + docid``, and top-k runs over each
+row — docids are the top-k indices themselves, and
 equal scores tie-break toward the smaller index, which IS the canonical
 (score desc, docid asc) order.  Frozen and delta docid spaces are disjoint,
-so accumulating both parts into one array is exact.  This replaces an
-earlier argsort + segmented-scan sparse path: cap+1 is far smaller than the
-padded posting count R·MB·B, and a scatter-add is linear where the sort is
-O(P log P) — measured ~5× cheaper end-to-end on CPU at bench scale.
+so accumulating both parts into one array is exact.  Pad slots decode to
+nothing: their postings add exactly 0 to column 0 of row 0.
 """
 
 from __future__ import annotations
@@ -188,46 +186,44 @@ def decode_blocks_parallel(blocks: jnp.ndarray, start: jnp.ndarray,
 
 
 def _part_postings(part, F: int):
-    """Decode one packed image part into per-posting (docid, f, valid).
+    """Decode one image's flat pool into per-posting (docid, f, valid).
 
-    ``part`` is (gat, start, end, seg, lastd0, dnum0, widf): gat (TQ, PB, B)
-    packed chain blocks (term-major per query), seg (TQ, PB) the owning
-    term's segment id (≥ T for empty pad slots), lastd0/dnum0/widf
-    (TQ, PB) the owning term's chaining bases and idf weight per slot.
+    ``part`` is (gat, start, end, seg, lastd0, dnum0, widf, qix): gat
+    (NS, B) chain blocks packed query-major then term-major, seg (NS,) the
+    owning (query, term) segment id (one id past the last for pad slots),
+    lastd0/dnum0/widf (NS,) the owning term's chaining bases and idf weight
+    per slot, qix (NS,) the owning query's row.
     """
-    gat, start, end, seg, lastd0, dnum0, widf = part
-    TQ, PB, B = gat.shape
-    g, f, valid = decode_blocks_parallel(
-        gat.reshape(TQ * PB, B), start.reshape(-1), end.reshape(-1), F)
-    g = g.reshape(TQ, PB, B)
-    f = f.reshape(TQ, PB, B)
-    valid = valid.reshape(TQ, PB, B)
+    gat, start, end, seg, lastd0, dnum0 = part[:6]
+    g, f, valid = decode_blocks_parallel(gat, start, end, F)   # (NS, B)
     # ---- docid reconstruction (uniform frozen/delta chaining) ------------
     gv = jnp.where(valid, g, 0)
-    within = _cumsum(gv, axis=2)
-    vcum = _cumsum(valid.astype(jnp.int32), axis=2)
-    first_gap = jnp.max(jnp.where(vcum == 1, gv, 0), axis=2)   # (TQ, PB)
-    # chain arithmetic per term segment: the head block's first docid is
+    within = _cumsum(gv, axis=1)
+    vcum = _cumsum(valid.astype(jnp.int32), axis=1)
+    first_gap = jnp.max(jnp.where(vcum == 1, gv, 0), axis=1)   # (NS,)
+    # chain arithmetic per segment: the head block's first docid is
     # lastd0 + its first gap; later blocks sit at dnum_eff + the running
     # sum of first gaps (head's excluded), dnum_eff resolving the frozen
     # -1 sentinel to the head block's own first gap
-    is_head = seg != _shift_right(seg, 1, 1, -1)
+    is_head = seg != _shift_right(seg, 1, 0, -1)
     fg_head = jnp.maximum(_seg_cummax(
         jnp.where(is_head, first_gap, jnp.iinfo(jnp.int32).min), seg,
-        axis=1), 0)
-    s_cum = _seg_cumsum(first_gap, seg, axis=1)
+        axis=0), 0)
+    s_cum = _seg_cumsum(first_gap, seg, axis=0)
     dnum_eff = jnp.where(dnum0 < 0, fg_head, dnum0)
     block_first = jnp.where(is_head, lastd0 + first_gap,
                             dnum_eff + (s_cum - fg_head))
-    docid = block_first[:, :, None] + (within - first_gap[:, :, None])
-    docid = jnp.where(valid, docid, 0)                 # (TQ, PB, B)
-    return docid, f, valid, widf
+    docid = block_first[:, None] + (within - first_gap[:, None])
+    docid = jnp.where(valid, docid, 0)                 # (NS, B)
+    return docid, f, valid
 
 
-def _scatter_add(acc: jnp.ndarray, docs: jnp.ndarray,
-                 vals: jnp.ndarray) -> jnp.ndarray:
-    """Per-query dense scatter-add into the (TQ, cap+1) accumulator."""
-    return jax.vmap(lambda a, d, v: a.at[d].add(v))(acc, docs, vals)
+def _scatter_add(acc: jnp.ndarray, qix: jnp.ndarray, docid: jnp.ndarray,
+                 vals: jnp.ndarray, width: int) -> jnp.ndarray:
+    """Add ``vals`` (NS, B) into the flat accumulator at
+    ``qix·width + docid``: row ``qix`` of its (Qn, width) view."""
+    idx = qix[:, None] * width + docid
+    return acc.at[idx.reshape(-1)].add(vals.reshape(-1))
 
 
 def _unpack_alive(alive: jnp.ndarray, cap: int) -> jnp.ndarray:
@@ -244,17 +240,17 @@ def _unpack_alive(alive: jnp.ndarray, cap: int) -> jnp.ndarray:
 
 def fused_tile(parts, nterms, doclens, bm25_norm, *, mode: str, k: int,
                F: int, cap: int, alive=None):
-    """Decode → docids → score → select for a tile of queries.
+    """Decode → docids → score → select for a launch's queries.
 
     Args:
-      parts: per-image tuples (gat, start, end, seg, lastd0, dnum0, widf) —
-        gat (TQ, PB_i, B) uint8 packed chain blocks (per-image packed
-        capacity), start/end (TQ, PB_i) i32 payload byte bounds
-        (end 0 = empty slot), seg (TQ, PB_i) i32 owning-term segment ids,
-        lastd0/dnum0 (TQ, PB_i) i32 docid-chaining bases (dnum0 -1 ⇒
-        frozen absolute chain), widf (TQ, PB_i) f32 idf weights
-        (0 for pad slots).
-      nterms: (TQ,) i32 — live terms per query (conjunctive only).
+      parts: per-image flat pools (gat, start, end, seg, lastd0, dnum0,
+        widf, qix) — gat (NS_i, B) uint8 chain blocks (per-image pool
+        size), start/end (NS_i,) i32 payload byte bounds (end 0 = pad
+        slot), seg (NS_i,) i32 (query, term) segment ids, lastd0/dnum0
+        (NS_i,) i32 docid-chaining bases (dnum0 -1 ⇒ frozen absolute
+        chain), widf (NS_i,) f32 idf weights (0 for pad slots), qix
+        (NS_i,) i32 query rows (0 for pad slots).
+      nterms: (Qn,) i32 — live terms per query (conjunctive only).
       doclens: (cap+1,) f32 — document lengths (bm25 only, else shape (1,)).
       bm25_norm: (2,) f32 — (k1*(1-b), k1*b/avgdl) (bm25 only).
       mode: "conjunctive" | "ranked_tfidf" | "bm25".
@@ -266,41 +262,43 @@ def fused_tile(parts, nterms, doclens, bm25_norm, *, mode: str, k: int,
         are masked out of the accumulator before selection, so the fused
         path matches the host path under deletes.
 
-    Returns ``matches (TQ, cap+1) bool`` for conjunctive, else
-    ``(top_d (TQ, kk) i32, top_s (TQ, kk) f32)`` with kk = min(k, cap+1),
+    Returns ``matches (Qn, cap+1) bool`` for conjunctive, else
+    ``(top_d (Qn, kk) i32, top_s (Qn, kk) f32)`` with kk = min(k, cap+1),
     descending score, ties broken by ascending docid (canonical order).
     """
-    TQ = parts[0][0].shape[0]
+    Qn, W = nterms.shape[0], cap + 1
     if mode == "conjunctive":
-        hits = jnp.zeros((TQ, cap + 1), jnp.int32)
+        hits = jnp.zeros(Qn * W, jnp.int32)
         for part in parts:
             with jax.named_scope("decode"):
-                docid, _f, valid, _w = _part_postings(part, F)
+                docid, _f, valid = _part_postings(part, F)
             with jax.named_scope("accumulate"):
-                hits = _scatter_add(hits, docid.reshape(TQ, -1),
-                                    valid.reshape(TQ, -1).astype(jnp.int32))
+                hits = _scatter_add(hits, part[-1], docid,
+                                    valid.astype(jnp.int32), W)
         with jax.named_scope("topk"):
+            hits = hits.reshape(Qn, W)
             matches = (hits == nterms[:, None]) & (nterms[:, None] > 0)
             if alive is not None:
                 matches = matches & _unpack_alive(alive, cap)[None, :]
             return matches.at[:, 0].set(False)
-    score = jnp.zeros((TQ, cap + 1), jnp.float32)
+    score = jnp.zeros(Qn * W, jnp.float32)
     for part in parts:
         with jax.named_scope("decode"):
-            docid, f, valid, widf = _part_postings(part, F)
+            docid, f, valid = _part_postings(part, F)
         with jax.named_scope("accumulate"):
+            *_, widf, qix = part
             fv = jnp.where(valid, f, 0).astype(jnp.float32)
             if mode == "bm25":
-                dl = doclens[docid]                    # (TQ, PB, B)
+                dl = doclens[docid]                    # (NS, B)
                 tf = (fv * (BM25_K1 + 1.0)) / (
                     fv + bm25_norm[0] + bm25_norm[1] * dl)
-                w = tf * widf[:, :, None]
+                w = tf * widf[:, None]
             else:
-                w = precise_log1p(fv) * widf[:, :, None]
+                w = precise_log1p(fv) * widf[:, None]
             w = jnp.where(valid, w, 0.0)
-            score = _scatter_add(score, docid.reshape(TQ, -1),
-                                 w.reshape(TQ, -1))
+            score = _scatter_add(score, qix, docid, w, W)
     with jax.named_scope("topk"):
+        score = score.reshape(Qn, W)
         if alive is not None:
             # mask by select, not multiply: a fully-deleted term's padded
             # idf could be ±inf, and inf * 0 would poison the accumulator

@@ -127,7 +127,7 @@ def test_fused_matches_host_matrix(eng, backend, mode):
 
 @pytest.mark.parametrize("mode", MODES)
 def test_fused_batch_equals_singletons(eng, mode):
-    """Batched execution (one launch, query-major grid) returns exactly the
+    """Batched execution (one launch, one flat pool) returns exactly the
     per-query results — padding rows never leak."""
     vocab, e = eng
     batch = _batch(vocab, mode, n=5, seed=23)
@@ -359,3 +359,141 @@ def test_query_service_hands_whole_batch_deduped(eng):
         assert t[2].result is not t[0].result  # private copies
     finally:
         e.execute_many = real
+
+
+# --------------------------------------------------------------------------
+# flat pool: a skewed batch, the pool size, the pool counters
+# --------------------------------------------------------------------------
+
+COMMON = tuple(f"c{i}" for i in range(6))
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["no_deletes", "deletes"])
+def skewed(request):
+    """6,000 docs that all hold the six COMMON terms (1–3 times each, so
+    their chains run to ~100 blocks apiece) plus 2–7 of 300 tail terms;
+    the last 600 sit in the delta image.  With deletes, every 7th doc of
+    both images is tombstoned."""
+    rng = np.random.default_rng(5)
+    tail = [f"w{i}" for i in range(300)]
+    docs = [[t for t in COMMON for _ in range(int(rng.integers(1, 4)))]
+            + [tail[j] for j in rng.integers(0, 300,
+                                             size=rng.integers(2, 8))]
+            for _ in range(6000)]
+    e = Engine(B=64, growth="const")
+    e.add_documents(docs[:5400])
+    e.collate_now()
+    e.add_documents(docs[5400:])
+    if request.param:
+        for d in range(1, 6001, 7):
+            e.delete_document(d)
+    return tail, e
+
+
+def _skewed_batch(tail, mode):
+    """One query over every COMMON term (hundreds of chain blocks), eleven
+    1-term queries and one whose terms are all unknown."""
+    return ([Query(terms=COMMON, mode=mode, k=10)]
+            + [Query(terms=(tail[i],), mode=mode, k=10)
+               for i in range(0, 220, 20)]
+            + [Query(terms=("zz_unknown1", "zz_unknown2"), mode=mode, k=10)])
+
+
+def _owned_blocks(e, batch):
+    """Chain blocks the batch's known terms own, per resident image (read
+    from the images themselves)."""
+    ids = [e.term_id(t) for q in batch for t in q.terms]
+    ids = [t for t in ids if t is not None]
+    return [int(np.asarray(img.term_nblk)[ids].sum())
+            for img in e.resident.images]
+
+
+@pytest.mark.parametrize("backend", ["device", "pallas"])
+@pytest.mark.parametrize("mode", MODES)
+def test_skewed_batch_matches_host(skewed, mode, backend):
+    """Every query of a batch whose block totals are skewed — one query
+    owns hundreds of blocks, the others a few or none — answers as the
+    host backend does, each from its own rows of the shared pool."""
+    tail, e = skewed
+    batch = _skewed_batch(tail, mode)
+    got = e.execute_many([Query(terms=q.terms, mode=mode, k=10,
+                                backend=backend) for q in batch])
+    assert _owned_blocks(e, batch[:1])[0] >= 300
+    for q, r in zip(batch, got):
+        assert r.backend == backend
+        want = e.execute(Query(terms=q.terms, mode=mode, k=10,
+                               backend="host"))
+        if mode == "conjunctive":
+            assert r.docids.tolist() == want.docids.tolist()
+            continue
+        assert np.allclose(r.scores, want.scores, rtol=1e-5)
+        # every served document scores what the host gives it (robust to
+        # ties at the k-th place), and none is tombstoned
+        full = e.execute(Query(terms=q.terms, mode=mode,
+                               k=e.index.num_docs, backend="host"))
+        score_of = dict(zip(full.docids.tolist(), full.scores.tolist()))
+        assert np.allclose([score_of[d] for d in r.docids.tolist()],
+                           r.scores, rtol=1e-5)
+    assert got[-1].docids.size == 0
+
+
+def test_pool_size_is_pow2_of_owned_blocks(skewed):
+    """Each image's pool holds pow2(Σ blocks the launch's queries own
+    there) slots, floor 8 — one pool for the launch, not one per query."""
+    from repro.engine.device_backend import _prep_launch
+    tail, e = skewed
+    e.resident.refresh()
+    for batch in (_skewed_batch(tail, "bm25"),
+                  [Query(terms=(tail[3],), mode="bm25", k=10)]):
+        owned = _owned_blocks(e, batch)
+        live, qt, qm, caps, total = _prep_launch(e, e.resident, batch,
+                                                 "bm25")
+        assert caps == tuple(max(8, 1 << (n - 1).bit_length())
+                             for n in owned)
+        assert total == sum(owned)
+    assert caps[1] == 8                  # one short delta chain: the floor
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_pool_counters_count_each_launch(skewed, mode):
+    """``pool_blocks``/``pool_slots`` grow by exactly the launch's owned
+    blocks and pool slots over both images; padded slots stay well under
+    what one (pow2 of the longest query) row per query would have held."""
+    tail, e = skewed
+    batch = _skewed_batch(tail, mode)
+    e.resident.refresh()
+    before = e.stats()
+    slots0, blocks0 = before.pool_slots, before.pool_blocks
+    fused_execute(e, e.resident, batch, mode, 10, flavor="ref",
+                  name="device")
+    owned = _owned_blocks(e, batch)
+    slots = sum(max(8, 1 << (n - 1).bit_length()) for n in owned)
+    after = e.stats()
+    assert after.pool_blocks - blocks0 == sum(owned)
+    assert after.pool_slots - slots0 == slots
+    live = len(batch) - 1                # the all-unknown query is not sent
+    per_query = sum(max(8, 1 << (max(_owned_blocks(e, [q])[i]
+                                     for q in batch) - 1).bit_length())
+                    for i in range(2))
+    assert slots * 4 <= (1 << (live - 1).bit_length()) * per_query
+
+
+def test_sharded_stats_sum_pool_counters(zdocs):
+    """The fleet's ``pool_slots``/``pool_blocks`` are its shards' sums."""
+    from repro.core.sharded_index import ShardedEngine
+    vocab, docs = zdocs
+    se = ShardedEngine(num_shards=2, B=64, growth="const")
+    try:
+        for d in docs[:120]:
+            se.add_document(d)
+        se.collate_now()
+        se.execute_many([Query(terms=(vocab[i], vocab[i + 1]), mode="bm25",
+                               k=10) for i in range(5)])
+        shards = [e.stats() for e in se.engines]
+        s = se.stats()
+        assert s.pool_blocks == sum(x.pool_blocks for x in shards) > 0
+        assert s.pool_slots == sum(x.pool_slots for x in shards) \
+            >= s.pool_blocks
+    finally:
+        se.close()

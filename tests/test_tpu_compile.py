@@ -15,13 +15,14 @@ import os
 import numpy as np
 import pytest
 
-# Buckets chip_smoke.py reports on one chip at its default size: the
-# largest packed caps of its query log, after the post-freeze ingest and
-# the deletes (liveness mask on).
+# Buckets chip_smoke.py reports on one chip at its default size, after
+# the post-freeze ingest and the deletes (liveness mask on); the launch's
+# flat pools are sized for a 32-query msmarco-like batch (~1,300 owned
+# frozen blocks a query, pow2 of their sum) and 32 delta blocks a query.
 B = 64
 F = 4
 QN, T = 32, 4
-CAPS = (4096, 32)                # packed blocks per query: frozen, delta
+CAPS = (65536, 1024)             # pool slots a launch: frozen, delta
 FROZEN_BLOCKS = 1_095_518
 DELTA_BLOCKS = 131_072
 VOCAB = 1 << 19
