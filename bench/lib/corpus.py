@@ -7,7 +7,8 @@ term rank ``i`` rendered as the base-36 string of ``i + 31``.
 
 The collection is kept as term-id arrays (what the plain reference reads);
 :meth:`Collection.docs` renders a slice of it as term strings, the input
-the engine ingests.
+the engine ingests.  :func:`extend` continues a collection with the
+stream a live window ingests, from a second generator.
 """
 
 from __future__ import annotations
@@ -64,10 +65,10 @@ class Collection:
         return self._lens
 
 
-def generate(n_docs: int, words_per_doc: float, zipf_s: float,
-             universe: int, seed: int) -> Collection:
-    """The first ``n_docs`` documents of the stream seeded by ``seed``."""
-    rng = np.random.default_rng(seed)
+def _draw(n_docs: int, words_per_doc: float, zipf_s: float, universe: int,
+          rng: np.random.Generator) -> tuple[np.ndarray, np.ndarray]:
+    """(ptr, ids) of ``n_docs`` documents drawn from ``rng``: every length
+    first, then every token."""
     ranks = np.arange(1, universe + 1, dtype=np.float64)
     p = ranks ** (-zipf_s)
     cdf = np.cumsum(p / p.sum())
@@ -78,4 +79,32 @@ def generate(n_docs: int, words_per_doc: float, zipf_s: float,
     np.cumsum(lens, out=ptr[1:])
     ids = np.searchsorted(cdf, rng.random(int(ptr[-1])),
                           side="right").astype(np.int32)
+    return ptr, ids
+
+
+def generate(n_docs: int, words_per_doc: float, zipf_s: float,
+             universe: int, seed: int) -> Collection:
+    """The first ``n_docs`` documents of the stream seeded by ``seed``."""
+    ptr, ids = _draw(n_docs, words_per_doc, zipf_s, universe,
+                     np.random.default_rng(seed))
     return Collection(ptr=ptr, ids=ids, names=term_names(universe))
+
+
+STREAM_BLOCK = 1024
+
+
+def extend(coll: Collection, base: int, n_docs: int, words_per_doc: float,
+           zipf_s: float, universe: int, seed: int) -> None:
+    """Append to ``coll`` the stream that continues it past document
+    ``base`` until it holds ``base + n_docs`` documents or more.  The
+    stream is drawn from a second generator, in blocks of
+    ``STREAM_BLOCK`` documents, block ``j`` from the seed sequence
+    ``(seed, 1, j)``: its documents are the same however many are asked
+    for, and the first ``base`` are never touched."""
+    while coll.n < base + n_docs:
+        j = (coll.n - base) // STREAM_BLOCK
+        ptr, ids = _draw(STREAM_BLOCK, words_per_doc, zipf_s, universe,
+                         np.random.default_rng(np.random.SeedSequence(
+                             [seed % (1 << 64), 1, j])))
+        coll.ptr = np.concatenate([coll.ptr, coll.ptr[-1] + ptr[1:]])
+        coll.ids = np.concatenate([coll.ids, ids])

@@ -35,7 +35,7 @@ class Reference:
     """Per-term docid lists over documents 1..n of a collection."""
 
     def __init__(self, ptr: np.ndarray, ids: np.ndarray):
-        n = len(ptr) - 1
+        self.n = n = len(ptr) - 1
         lens = np.diff(ptr)
         doc = np.repeat(np.arange(1, n + 1, dtype=np.int64), lens)
         key, f = np.unique(ids.astype(np.int64) * (n + 1) + doc,
@@ -99,6 +99,8 @@ class Readings:
     conj_wrong: int = 0      # conjunctive answers not equal to the reference
     rank_gap: float = 0.0    # served doc's reference score below the best
     score_gap: float = 0.0   # served score vs the reference's for that doc
+    ingest_unacked: int = 0  # ingested documents not acknowledged with
+    #                          the next docids
     compared: int = 0
     worst: dict = field(default_factory=dict)
 

@@ -13,7 +13,11 @@ modes cycled), rewritten here for one traffic file's rules:
 * terms from the band ``ranks[skip:top]`` of the vocabulary ranked by
   document frequency, picked ``uniform``-ly or by ``zipf`` over the band,
   without repeats inside one query;
-* modes cycled query by query.
+* modes cycled query by query;
+* where the traffic has ``ingest`` (``{"docs_per_s": r, "batch_docs": b}``),
+  ``round(r * seconds / b)`` ingest batches of ``b`` documents, at sorted
+  uniform times over the window from a stream of their own.  A traffic
+  without ``ingest`` gives the schedule it gave before the key existed.
 
 No clock, no global random state: the same seed and inputs give the same
 schedule.
@@ -40,6 +44,8 @@ class Schedule:
     k: int
     groups: list[list[int]]     # flush groups: consecutive query indices
     close_s: np.ndarray         # (len(groups),) when each group may flush
+    ingest_at_s: np.ndarray | None = None   # (batches,) scheduled ingests
+    batch_docs: int = 0         # documents in each ingest batch
 
 
 def _lengths(rule: dict, n: int, rng) -> np.ndarray:
@@ -102,6 +108,21 @@ def build(traffic: dict, ranked_terms: np.ndarray, seed: int,
     modes = traffic["modes"]
     groups, close = flush_groups(at, int(traffic["max_batch"]),
                                  float(traffic["deadline_ms"]) / 1e3)
-    return Schedule(at_s=at, terms=terms,
-                    modes=[modes[i % len(modes)] for i in range(n)],
-                    k=int(traffic["k"]), groups=groups, close_s=close)
+    out = Schedule(at_s=at, terms=terms,
+                   modes=[modes[i % len(modes)] for i in range(n)],
+                   k=int(traffic["k"]), groups=groups, close_s=close)
+    if "ingest" in traffic:
+        rule = traffic["ingest"]
+        out.batch_docs = int(rule["batch_docs"])
+        nb = round(float(rule["docs_per_s"]) * seconds / out.batch_docs)
+        out.ingest_at_s = np.sort(rng_for(seed, 3).uniform(0.0, seconds, nb))
+    return out
+
+
+def with_ingest(traffic: dict, docs_per_s: float, batch_docs: int) -> dict:
+    """``traffic`` with a write stream of ``docs_per_s`` documents a second
+    in batches of ``batch_docs`` overlaid, and the limit 0 on
+    ``ingest_unacked``."""
+    return dict(traffic, ingest={"docs_per_s": docs_per_s,
+                                 "batch_docs": batch_docs},
+                limits=dict(traffic["limits"], ingest_unacked=0))

@@ -5,7 +5,7 @@ and a duration in nanoseconds).  Device planes are ``/device:TPU:<n>``;
 their ``XLA Ops`` line holds the operations that ran, their ``XLA Modules``
 line one event per launched program.  The host's spans are the
 benchmark's own ``TraceAnnotation`` events: ``window`` around the measured
-window and ``flush`` inside it.  Host and device events
+window, and ``flush`` and ``ingest`` inside it.  Host and device events
 share the profiler's clock.
 
 Busy time is the union of the device's operation intervals inside the
@@ -20,7 +20,7 @@ import re
 from dataclasses import dataclass, field
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
-SPANS = ("window", "flush")
+SPANS = ("window", "flush", "ingest")
 
 
 def union(intervals):
@@ -105,8 +105,8 @@ class Trace:
 
     def idle_gaps(self, n: int = 10):
         """The longest device-idle gaps in the window, each named by the
-        host span around its midpoint (``flush`` or ``none``) and its
-        offset into the window."""
+        host span around its midpoint (``flush``, ``ingest`` or ``none``)
+        and its offset into the window."""
         win = self.window
         if not self.devices or win is None:
             return []
@@ -124,7 +124,8 @@ class Trace:
         out = []
         for s, e in gaps[:n]:
             mid = (s + e) / 2
-            name = next(("flush" for a, b in self.spans.get("flush", [])
+            name = next((span for span in ("flush", "ingest")
+                         for a, b in self.spans.get(span, [])
                          if a <= mid <= b), "none")
             out.append([f"{name}@{s - win[0]:.3f}s", e - s])
         return out

@@ -12,16 +12,20 @@ program's ``QueryService(Engine(...)).ingest_batch``, freezes it
 (``collate_now``), ingests the traffic's ``delta_docs`` after the freeze,
 runs every flush group of the window once to compile what it will use, then
 drives the window (``lib/driver.py``) for ``--seconds``: queries open loop
-at the traffic's rate.  Set-up time is process start to the window's start.
+at the traffic's rate and, where the traffic names ``ingest``, batches of
+the collection's continuing stream ingested on schedule beside them.
+Set-up time is process start to the window's start.
 
 After the window, with the program's state freed, every answer is compared
-with the plain reference (``lib/reference.py``) over the documents the
-index held; the numbers compared and their limits go to the last lines
+with the plain reference (``lib/reference.py``) at the documents its query
+was entitled to see (``Cell.check``); the numbers compared and their limits go to the last lines
 of standard error and under ``checks`` in the result.  The last line of
 standard output is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer metrics, read by ``bench/metrics/<name>.py``), ``device``, with
-``--trace 1`` a ``breakdown``, and ``checks``.
+``--trace 1`` a ``breakdown``, for an ingest traffic an ``ingest`` object
+(documents, UTF-8 bytes, MB/min, ack latency and start lateness), and
+``checks``.
 
 Without a TPU, or with fewer chips than the cell asks for, the run exits
 non-zero and prints no result.  ``--rehearse`` runs the same code on the
@@ -218,6 +222,10 @@ def main(argv=None) -> int:
     if args.rehearse:
         result["metrics"] = {}
         result["rehearsal"] = {"would_report": sorted(metrics)}
+    live = sut.ingest_report(run)
+    if live is not None:
+        result["ingest"] = live
+        log(f"ingest: {live}")
     result["checks"] = checks
     for name, c in checks.items():
         log(f"check {name} {c['value']!r} limit {c['limit']!r}")

@@ -1,9 +1,11 @@
 """Schedules are pure in their seed; flush groups close at size or deadline."""
 
+import hashlib
 import json
 import os
 
 import numpy as np
+import pytest
 
 from bench.lib import schedule
 
@@ -59,3 +61,60 @@ def test_term_names_are_base36_of_rank_plus_31():
     for i in (0, 4, 5, 1_265, 46_624, 49_999):
         assert names[i] == np.base_repr(i + 31, 36).lower()
     assert len(set(names.tolist())) == 50_000
+
+
+def digest(*parts) -> str:
+    h = hashlib.sha256()
+    for p in parts:
+        h.update(p.tobytes() if isinstance(p, np.ndarray) else
+                 repr(p).encode())
+    return h.hexdigest()[:16]
+
+
+# Taken from the benchmark as it was before traffic could name a write
+# stream: a traffic without ``ingest`` still yields these schedules, and
+# every configuration still holds these documents before any stream.
+SCHEDULES = {
+    ("query", 0): "142fb6dca90a6e83",
+    ("query", 7): "4d2f263760a605d4",
+    ("query", 2**31 + 17): "12b09ccdbc1f663f",
+    ("query", 2**40 + 3): "9c767ae25db13a16",
+    ("bm25_k1000", 0): "5044961926c756a8",
+    ("bm25_k1000", 7): "e9b631322c802143",
+    ("bm25_k1000", 2**31 + 17): "a20cdcde74065fd1",
+    ("bm25_k1000", 2**40 + 3): "e4cabd19b355c073",
+}
+COLLECTIONS = {
+    ("wsj1", "query", True): "d813a64f5ee72139",
+    ("wsj1", "query", False): "e8335887a734b471",
+    ("msmarco", "bm25_k1000", True): "444dca330deb19c5",
+}
+
+
+@pytest.mark.parametrize("name,seed", sorted(SCHEDULES))
+def test_schedule_without_ingest_is_unchanged(name, seed):
+    s = schedule.build(load(name), np.arange(5000), seed, 5.0)
+    got = digest(np.asarray(s.at_s, np.float64),
+                 (s.terms, s.modes, s.k, s.groups),
+                 np.asarray(s.close_s, np.float64))
+    assert got == SCHEDULES[name, seed]
+    assert s.ingest_at_s is None and s.batch_docs == 0
+
+
+@pytest.mark.parametrize("config,traffic,rehearse", sorted(COLLECTIONS))
+def test_collection_before_the_stream_is_unchanged(config, traffic,
+                                                   rehearse):
+    from bench.lib.cell import collection
+    with open(os.path.join(os.path.dirname(TRAFFIC), "configs",
+                           config + ".json")) as fh:
+        c = json.load(fh)
+    t = load(traffic)
+    if rehearse:
+        c.update(c["rehearse"])
+        t.update(t.get("rehearse", {}))
+    n = int(c["n_docs"]) + int(t.get("delta_docs", 0))
+    coll = collection(c, n)
+    assert coll.n == n
+    assert digest(np.asarray(coll.ptr, np.int64),
+                  np.asarray(coll.ids, np.int32)) == COLLECTIONS[
+                      config, traffic, rehearse]

@@ -62,3 +62,28 @@ def test_read_finds_the_benchmark_spans(tmp_path):
     assert all(ws <= s <= e <= we for s, e in tr.spans["flush"])
     assert tr.devices == []            # the CPU has no device plane
     assert tr.busy_s() is None
+
+
+def test_idle_gap_inside_an_ingest_is_named_ingest(recorded):
+    recorded.spans["ingest"] = [(8.0, 9.0)]
+    gaps = recorded.idle_gaps()
+    assert gaps[0] == ["none@3.000s", pytest.approx(3.0)]
+    assert gaps[1] == ["ingest@7.000s", pytest.approx(3.0)]
+    assert gaps[2] == ["flush@0.000s", pytest.approx(1.0)]
+
+
+def test_read_keeps_the_ingest_spans(tmp_path):
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 1
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    with jax.profiler.TraceAnnotation("window"):
+        with jax.profiler.TraceAnnotation("ingest"):
+            sum(range(1000))
+        with jax.profiler.TraceAnnotation("flush"):
+            sum(range(1000))
+    jax.profiler.stop_trace()
+    tr = tracing.read(tracing.xplane_path(str(tmp_path)))
+    (s, e), = tr.spans["ingest"]
+    (fs, _), = tr.spans["flush"]
+    assert s <= e <= fs

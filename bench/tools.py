@@ -5,6 +5,8 @@ benchmark's own runs.
         --seconds 10 --seed 1
     python bench/tools.py readings --workload wsj1.query --seeds 11,12,13 \\
         --seconds 10 --control 3
+    python bench/tools.py readings --workload wsj1.query --seeds 21 \\
+        --seconds 15 --control 0 --ingest 0:10,10:10,50:10,200:10
 
 ``sweep`` drives one window per rate, in the order given, on one build and
 prints, per rate, the latency percentiles, the completed rate and how late
@@ -15,6 +17,11 @@ prints the numbers ``correct`` compares for the program and, on the first
 ``--control`` seeds, for the control (the reference computed in bfloat16)
 on the same queries: the readings the limits are set from.  ``both`` does
 the sweep, then the readings at four fifths of the highest rate sustained.
+``--ingest <docs_per_s>:<batch_docs>`` overlays a write stream on the
+cell's traffic in memory (a comma list runs each overlay in turn, on the
+same build, the stream going on from one window to the next); each window
+then also reports the stream, the compiles and new launch keys of the
+window, the delta rebuilds, and the device's peak memory.
 Each result is one JSON line on standard output and, with ``--out``,
 appended to that file.
 """
@@ -34,6 +41,7 @@ import numpy as np  # noqa: E402
 
 from bench.lib import driver  # noqa: E402
 from bench.lib.cell import Cell, verdict  # noqa: E402
+from bench.lib.schedule import with_ingest  # noqa: E402
 from bench.run import (  # noqa: E402
     device_check, load_cell, log, open_program, place_cache)
 
@@ -67,6 +75,39 @@ def summary(run) -> dict:
     }
 
 
+def live(sut: Cell, run, start: dict) -> dict:
+    """What a window with a write stream did to the program: ``start`` is
+    the delta's size when the window was built."""
+    import jax
+    if run.schedule.ingest_at_s is None:
+        return {}
+    b, a = run.before, run.after
+    span = "repro.device.refresh"
+    rebuilds = run.delta("delta_refreshes")
+    refresh_s = (a["spans"].get(span, [0, 0.0])[1]
+                 - b["spans"].get(span, [0, 0.0])[1])
+    ms = [1e3 * (x.end - x.start) for x in run.window.ingests]
+    stats = jax.devices()[0].memory_stats() or {}
+    return {
+        "stream": sut.ingest_report(run), **start,
+        "ingest_ms_p50": float(np.median(ms)) if ms else None,
+        "ingest_ms_max": float(np.max(ms)) if ms else None,
+        "rebuilds": rebuilds,
+        "rebuilds_per_flush": rebuilds / max(1, len(run.window.flushes)),
+        "refresh_ms_per_rebuild": 1e3 * refresh_s / rebuilds
+        if rebuilds else None,
+        "compactions": run.delta("delta_compactions"),
+        "new_launch_keys": [list(k) for k in list(
+            sut.eng.resident.launch_buckets)[b["launch_keys"]:]],
+        "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0)),
+    }
+
+
+def delta_size(sut: Cell) -> dict:
+    return {"horizon_at_start": sut.horizon,
+            "delta_blocks_at_start": sut.eng.resident.delta_blocks}
+
+
 def sustained(row: dict, deadline_ms: float) -> bool:
     """The window kept up: it completed nine tenths of the offered rate
     (the window's length includes the last group's deadline), its last
@@ -83,8 +124,10 @@ def sweep(sut: Cell, name: str, rates, seed: int, seconds: float,
     traffic, best = sut.traffic, None
     for rate in rates:
         sut.traffic = dict(traffic, rate_qps=rate)
-        row = {"workload": name, "rate_qps": rate,
-               **summary(sut.window(seed, seconds))}
+        start = delta_size(sut)
+        run = sut.window(seed, seconds)
+        row = {"workload": name, "rate_qps": rate, **summary(run),
+               **live(sut, run, start)}
         row["sustained"] = sustained(row, float(traffic["deadline_ms"]))
         emit(row, out)
         if row["sustained"]:
@@ -98,9 +141,11 @@ def sweep(sut: Cell, name: str, rates, seed: int, seconds: float,
 def readings(sut: Cell, name: str, seeds, seconds: float, control: int,
              out: str | None) -> None:
     for n, seed in enumerate(seeds):
+        start = delta_size(sut)
         run = sut.window(seed, seconds)
         row = {"workload": name, "seed": seed,
-               "rate_qps": float(sut.traffic["rate_qps"]), **summary(run)}
+               "rate_qps": float(sut.traffic["rate_qps"]), **summary(run),
+               **live(sut, run, start)}
         t = time.perf_counter()
         got = sut.check(run)
         row["check_s"] = time.perf_counter() - t
@@ -123,6 +168,9 @@ def main() -> int:
     ap.add_argument("--control", type=int, default=3,
                     help="read the control on the first N seeds")
     ap.add_argument("--out", default=None)
+    ap.add_argument("--ingest", default="",
+                    help="docs_per_s:batch_docs[,...]: overlay a write "
+                         "stream on the cell's traffic")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     if args.rehearse:
@@ -135,18 +183,25 @@ def main() -> int:
     t = time.perf_counter()
     sut = Cell(config, traffic, log)
     log(f"build {time.perf_counter() - t:.3f} s")
-    if args.what != "readings":
-        best = sweep(sut, cell["name"], [float(r) for r in
-                                         args.rates.split(",")],
-                     args.seed, args.seconds, args.out)
-        log(f"highest rate sustained: {best}")
-        if args.what == "both":
-            # the readings run at four fifths of the knee
-            rate = 0.8 * best if best else 0.5 * float(args.rates.split(",")[0])
-            sut.traffic = dict(traffic, rate_qps=round(rate))
-    if args.what != "sweep":
-        readings(sut, cell["name"], [int(s) for s in args.seeds.split(",")],
-                 args.seconds, args.control, args.out)
+    overlays = [tuple(x.split(":")) for x in args.ingest.split(",") if x]
+    for docs_per_s, batch_docs in overlays or [(None, None)]:
+        base = traffic if docs_per_s is None else with_ingest(
+            traffic, float(docs_per_s), int(batch_docs))
+        sut.traffic = base
+        if args.what != "readings":
+            best = sweep(sut, cell["name"], [float(r) for r in
+                                             args.rates.split(",")],
+                         args.seed, args.seconds, args.out)
+            log(f"highest rate sustained: {best}")
+            if args.what == "both":
+                # the readings run at four fifths of the knee
+                rate = (0.8 * best if best
+                        else 0.5 * float(args.rates.split(",")[0]))
+                sut.traffic = dict(base, rate_qps=round(rate))
+        if args.what != "sweep":
+            readings(sut, cell["name"],
+                     [int(s) for s in args.seeds.split(",")],
+                     args.seconds, args.control, args.out)
     return 0
 
 
